@@ -19,8 +19,8 @@ Run:  PYTHONPATH=src python -m benchmarks.calibrate_bench [--smoke]
 (``--smoke``: smoke-preset grid + single-rep kernel rows, same checks,
 separate ``BENCH_calibrate_smoke.json`` — the CI step.)
 
-All wall times are CPU numbers for this container; the harness
-calibrates whatever backend it runs on.
+Wall times are those of the device it runs on (the JSON names it); the
+harness calibrates whatever backend it runs on.
 """
 
 from __future__ import annotations
@@ -38,6 +38,7 @@ from repro.core.study import (
     Study,
     WorkloadSpec,
 )
+from repro._jax_compat import use_compile_cache
 
 from .kernels_bench import bench_kernels
 
@@ -140,9 +141,10 @@ def main():
              else "  -  ")
         print(f"{r['name']:<45} {r['us']:>12.1f} us  {s:>7} vs pre-opt")
     e = cal["errors"]
+    u = e["uncalibrated_holdout_median_rel_err"]
     print(
         f"fit: holdout err {e['holdout_median_rel_err']:.1%} "
-        f"(uncalibrated {e['uncalibrated_holdout_median_rel_err']:.1%}); "
+        f"(uncalibrated {'n/a: no published peak' if u is None else f'{u:.1%}'}); "
         f"roundtrip bit-identical: {identical}"
     )
 
@@ -151,4 +153,5 @@ ALL = [bench_speedups, bench_calibration]
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

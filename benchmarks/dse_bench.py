@@ -28,6 +28,7 @@ import numpy as np
 from repro.core.analytical import optimal_tiers
 from repro.core.dse import fig7_study, random_workloads
 from repro.core.engine import optimal_tiers_batched
+from repro._jax_compat import use_compile_cache
 
 HERE = pathlib.Path(__file__).resolve().parent
 BUDGETS = (2**14, 2**16, 2**18)
@@ -87,4 +88,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

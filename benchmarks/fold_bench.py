@@ -35,6 +35,7 @@ import time
 from repro.core.bandwidth import BandwidthSpec
 from repro.core.engine import schedule
 from repro.core.network import lower_zoo
+from repro._jax_compat import use_compile_cache
 
 HERE = pathlib.Path(__file__).resolve().parent
 
@@ -121,4 +122,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -26,6 +26,7 @@ from repro.kernels.dos_matmul import dos_matmul
 from repro.kernels.flash_attention import decode_attention
 from repro.kernels.flash_attention.ops import flash_attention_jnp
 from repro.kernels.ssm_scan import ssm_scan
+from repro._jax_compat import use_compile_cache
 
 HERE = pathlib.Path(__file__).resolve().parent
 
@@ -120,4 +121,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.core.network import lower_zoo
 from repro.core.study import AnalysisSpec, SpaceSpec, Study, WorkloadSpec
+from repro._jax_compat import use_compile_cache
 
 HERE = pathlib.Path(__file__).resolve().parent
 
@@ -119,4 +120,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

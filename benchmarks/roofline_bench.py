@@ -33,6 +33,7 @@ from repro.core.bandwidth import BandwidthSpec, gemm_traffic_batched, roofline_c
 from repro.core.dse import random_workloads
 from repro.core.engine import DesignGrid, evaluate
 from repro.core.study import AnalysisSpec, SpaceSpec, Study, WorkloadSpec
+from repro._jax_compat import use_compile_cache
 
 HERE = pathlib.Path(__file__).resolve().parent
 BUDGETS = (2**14, 2**16, 2**18)
@@ -220,4 +221,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

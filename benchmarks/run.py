@@ -14,6 +14,8 @@ from __future__ import annotations
 import sys
 import traceback
 
+from repro._jax_compat import use_compile_cache
+
 
 def main() -> None:
     from . import kernels_bench, paper_figs, roofline_bench
@@ -34,4 +36,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

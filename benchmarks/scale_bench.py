@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core.cache import ResultCache
 from repro.core.dse import fig7_study
+from repro._jax_compat import use_compile_cache
 
 HERE = pathlib.Path(__file__).resolve().parent
 BUDGETS = (2**14, 2**16, 2**18)
@@ -141,4 +142,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

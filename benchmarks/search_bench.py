@@ -48,6 +48,7 @@ from repro.core.study import (
     Study,
     WorkloadSpec,
 )
+from repro._jax_compat import use_compile_cache
 
 HERE = pathlib.Path(__file__).resolve().parent
 GEMMS = ((64, 12100, 147), (512, 784, 128))
@@ -255,4 +256,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

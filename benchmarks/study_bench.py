@@ -33,6 +33,7 @@ from repro.core.dse import PAPER_WORKLOADS
 from repro.core.engine import DesignGrid, evaluate, schedule
 from repro.core.network import lower_network
 from repro.core.study import AnalysisSpec, SpaceSpec, Study, WorkloadSpec
+from repro._jax_compat import use_compile_cache
 
 HERE = pathlib.Path(__file__).resolve().parent
 OVERHEAD_TARGET_PCT = 5.0
@@ -153,4 +154,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -45,6 +45,7 @@ from repro.core.study import (
     TrafficSpec,
     WorkloadSpec,
 )
+from repro._jax_compat import use_compile_cache
 
 HERE = pathlib.Path(__file__).resolve().parent
 
@@ -214,4 +215,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -337,6 +337,7 @@ def calibrate_section(cache=None):
     out = Study.example("calibrate").run(cache=cache)
     p = out.payload
     e = p["errors"]
+    u = e["uncalibrated_holdout_median_rel_err"]
     lines = [
         "### Calibrated roofline (kind='calibrate')",
         "",
@@ -345,8 +346,8 @@ def calibrate_section(cache=None):
         f"Backend: `{jax.default_backend()}`. Fitted DRAM "
         f"{p['dram_gbs_fitted']:.2f} GB/s; holdout median relative error "
         f"{e['holdout_median_rel_err']:.1%} vs "
-        f"{e['uncalibrated_holdout_median_rel_err']:.1%} for the "
-        "uncalibrated nominal constants. The `artifact` in the study "
+        f"{'n/a (no published peak)' if u is None else f'{u:.1%}'} for the "
+        "device's uncalibrated peak constants. The `artifact` in the study "
         "payload is a `CalibratedBandwidth` any other study accepts via "
         "`bandwidth=`.",
         "",

@@ -1,63 +1,47 @@
-"""Shims for jax API drift (0.4.x image vs >= 0.5/0.7 APIs).
+"""JAX set-up shared by the repo's entry points.
 
-Every version-dependent lookup lives here so a future jax bump is a
-one-file change: `shard_map`, Pallas `CompilerParams`,
-`make_mesh(axis_types=...)`, `lax.pcast`, and the `cost_analysis()`
-return shape.
+Written for jax 0.9.0. Two things live here:
+
+- ``make_mesh``: jax 0.9's ``jax.make_mesh`` defaults to Explicit axis
+  types, while the repo's sharding rules (``parallel.axes``) rely on
+  Auto axes — sharding constraints the compiler propagates — so every
+  mesh is built here.
+- ``use_compile_cache``: where JAX's persistent compilation cache
+  lives. Entry points (``python -m repro``, ``chip_smoke.py``, the
+  benchmark scripts) call it once at start-up; importing this module
+  changes nothing.
 """
 
 from __future__ import annotations
 
+import os
+import pathlib
+
 import jax
+from jax.sharding import AxisType
 
-__all__ = [
-    "shard_map",
-    "pallas_tpu_compiler_params",
-    "make_mesh",
-    "pcast",
-    "unwrap_cost_analysis",
-]
+__all__ = ["make_mesh", "use_compile_cache", "REPO_COMPILE_CACHE"]
 
-# shard_map: top-level `jax.shard_map` since ~0.6; experimental before,
-# where it also lacks replication rules for checkpoint_name etc. — so
-# the fallback skips the (new-jax-only) replication check.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, **kw):
-        kw.setdefault("check_rep", False)
-        return _experimental_shard_map(f, **kw)
-
-
-def pallas_tpu_compiler_params():
-    """`pltpu.CompilerParams`, named `TPUCompilerParams` before jax 0.5."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    return getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+#: The cache directory when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: fixed at the repository root, since a cache whose path moves between
+#: runs never hits.
+REPO_COMPILE_CACHE = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis types where supported.
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
-    jax < 0.5 has no AxisType / axis_types kwarg; Auto is the default
-    behavior there, so omitting it is equivalent.
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    A set ``JAX_COMPILATION_CACHE_DIR`` wins: JAX reads it itself, and
+    nothing else is configured. Otherwise the cache goes to
+    ``REPO_COMPILE_CACHE``.
     """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def pcast(x, axes, to):
-    """`jax.lax.pcast`, identity on jax < 0.7 (no varying-type system)."""
-    fn = getattr(jax.lax, "pcast", None)
-    return x if fn is None else fn(x, axes, to=to)
-
-
-def unwrap_cost_analysis(cost):
-    """jax < 0.5 wraps the compiled cost dict in a single-element list."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0]
-    return cost
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_COMPILE_CACHE))
+    return str(REPO_COMPILE_CACHE)

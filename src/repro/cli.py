@@ -37,6 +37,7 @@ import pathlib
 import subprocess
 import sys
 
+from ._jax_compat import use_compile_cache
 from .core.cache import DEFAULT_CACHE_DIR, ResultCache
 from .core.study import ANALYSIS_KINDS, Study
 
@@ -108,10 +109,11 @@ def _cmd_run(args) -> int:
     if args.workers is not None:
         # an execution knob (never part of the cache key): override in
         # place so --resume composes across worker counts
-        study = dataclasses.replace(
-            study,
-            analysis=dataclasses.replace(study.analysis, workers=args.workers),
-        )
+        try:
+            analysis = dataclasses.replace(study.analysis, workers=args.workers)
+        except ValueError as e:
+            raise SystemExit(f"error: --workers {args.workers}: {e}") from None
+        study = dataclasses.replace(study, analysis=analysis)
     if cache is None and args.cache is not None:
         cache = ResultCache(args.cache or DEFAULT_CACHE_DIR)
     result = study.run(cache=cache)
@@ -234,6 +236,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     return args.fn(args)
 
 

@@ -41,9 +41,12 @@ Study via ``AnalysisSpec(bandwidth=...)`` (the spec layer unwraps it
 to its embedded ``BandwidthSpec``, so a re-loaded artifact reproduces
 bit-identical results).
 
-Wall-clock numbers here are *backend* numbers (CPU in this container,
-TPU on real hardware) — the harness calibrates whatever backend it
-runs on, which is exactly what makes the model defensible there.
+Wall-clock numbers here are *backend* numbers — the harness
+calibrates whatever device it runs on, and every measured row records
+that device's ``device_kind``. Efficiencies and the uncalibrated
+baseline are taken against that device's published peaks
+(``DEVICE_PEAKS``), never against an assumed chip: a kind missing from
+the table is an error.
 """
 
 from __future__ import annotations
@@ -57,9 +60,10 @@ import numpy as np
 
 from .bandwidth import BandwidthSpec
 from .params import validate_option
-from .ppa import constants as HW
 
 __all__ = [
+    "DEVICE_PEAKS",
+    "device_peaks",
     "CALIBRATE_FAMILIES",
     "CALIBRATE_PRESETS",
     "CalibrateSpec",
@@ -79,6 +83,27 @@ _SSM_CHUNK = 32
 
 _F32 = 4  # bytes per f32 word (attention/SSM operand dtype)
 _BF16 = 2  # bytes per bf16 word (GEMM operand dtype)
+
+#: Published per-chip peaks of the devices calibration runs on, keyed
+#: by ``jax.Device.device_kind``: (bf16 FLOP/s, HBM bytes/s). Source:
+#: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s).
+#: ``None`` marks a known device with no published peak (the host CPU):
+#: its fit reports measured rates and errors but no efficiency and no
+#: uncalibrated baseline.
+DEVICE_PEAKS: dict[str, tuple[float, float] | None] = {
+    "TPU v5 lite": (197e12, 819e9),
+    "cpu": None,
+}
+
+
+def device_peaks(kind: str) -> tuple[float, float] | None:
+    """``DEVICE_PEAKS[kind]``; a kind not in the table raises."""
+    if kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peak for device kind {kind!r}; add it to "
+            f"calibrate.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)})"
+        )
+    return DEVICE_PEAKS[kind]
 
 
 # ---------------------------------------------------------------------------
@@ -269,16 +294,13 @@ def _kernel_fn(family: str, mode: str = ""):
     import jax.numpy as jnp
 
     from ..kernels.dos_matmul import dos_matmul
-    from ..kernels.flash_attention import decode_attention
-    from ..kernels.flash_attention.ops import flash_attention_jnp
+    from ..kernels.flash_attention import decode_attention, flash_attention
     from ..kernels.ssm_scan import ssm_scan
 
     if family == "gemm":
         return jax.jit(lambda a, b: dos_matmul(a, b))
     if family == "attention" and mode == "prefill":
-        return jax.jit(
-            lambda q, k, v: flash_attention_jnp(q, k, v, causal=True)
-        )
+        return jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
     if family == "attention":
         return jax.jit(
             lambda q, kc, vc, length: decode_attention(q, kc, vc, length=length)
@@ -320,7 +342,7 @@ def measure_row(row: dict, *, reps: int = 5, warmup: int = 2,
     """Measure one calibration row: AOT-compile the cached jitted
     wrapper for the row's shapes, run ``warmup`` untimed calls, then
     ``reps`` individually-timed calls. Returns a JSON-safe record with
-    the median time and achieved FLOP/s / GB/s."""
+    the median time, achieved FLOP/s / GB/s and the device kind."""
     import jax
 
     args = _build_inputs(row, seed)
@@ -341,6 +363,7 @@ def measure_row(row: dict, *, reps: int = 5, warmup: int = 2,
         reps=int(reps),
         achieved_gflops=row["flops"] / t_s / 1e9,
         achieved_gbs=row["bytes"] / t_s / 1e9,
+        device_kind=jax.devices()[0].device_kind,
     )
     return out
 
@@ -384,8 +407,19 @@ def fit_rows(measured: list[dict], spec: CalibrateSpec,
     rows (relative-error-weighted LSQ on the overhead-stripped
     residual). Returns the payload dict (fit + per-bucket errors + the
     ``CalibratedBandwidth`` artifact).
+
+    Every row must record the same ``device_kind``; efficiencies and the
+    uncalibrated baseline use that device's ``DEVICE_PEAKS`` entry.
     """
     from ..analysis.roofline import roofline_terms_batched
+
+    kinds = {r.get("device_kind") for r in measured}
+    if len(kinds) != 1 or None in kinds:
+        raise ValueError(
+            f"calibration rows must all record one device_kind, got {kinds}"
+        )
+    (kind,) = kinds
+    peaks = device_peaks(kind)
 
     fams = tuple(sorted({r["family"] for r in measured}))
     F = np.asarray([r["flops"] for r in measured], dtype=np.float64)
@@ -433,10 +467,15 @@ def fit_rows(measured: list[dict], spec: CalibrateSpec,
 
     pred = _predict(F, B, rates, bw, over, fam)
     rel = np.abs(pred - t) / t
-    # the uncalibrated model: nominal peak FLOP/s and HBM bandwidth
-    nominal = {f: float(HW.TPU_PEAK_FLOPS_BF16) for f in fams}
-    pred0 = _predict(F, B, nominal, float(HW.TPU_HBM_BW), {}, fam)
-    rel0 = np.abs(pred0 - t) / t
+    if peaks is None:
+        peak_flops, rel0, efficiency = None, None, {}
+    else:
+        # the uncalibrated model: the device's peak FLOP/s and HBM rate
+        peak_flops, peak_bw = peaks
+        nominal = {f: peak_flops for f in fams}
+        pred0 = _predict(F, B, nominal, peak_bw, {}, fam)
+        rel0 = np.abs(pred0 - t) / t
+        efficiency = {f: rates[f] / peak_flops for f in fams}
 
     def _med(mask) -> float:
         return float(np.median(rel[mask])) if mask.any() else math.nan
@@ -444,26 +483,25 @@ def fit_rows(measured: list[dict], spec: CalibrateSpec,
     errors = {
         "fit_median_rel_err": _med(fit),
         "holdout_median_rel_err": _med(hold) if hold.any() else _med(fit),
-        "uncalibrated_holdout_median_rel_err": float(
+        "uncalibrated_holdout_median_rel_err": None if rel0 is None else float(
             np.median(rel0[hold if hold.any() else fit])
         ),
         "per_family_median_rel_err": {f: _med(fam == f) for f in fams},
     }
-    efficiency = {f: rates[f] / float(HW.TPU_PEAK_FLOPS_BF16) for f in fams}
     artifact = CalibratedBandwidth(
         bandwidth=BandwidthSpec(dram_gbs=bw / 1e9),
         efficiency=efficiency,
-        peak_flops=float(HW.TPU_PEAK_FLOPS_BF16),
+        peak_flops=peak_flops,
         diagnostics=dict(
             errors, n_rows=len(measured), n_holdout=int(hold.sum()),
             families=list(fams), preset=spec.preset,
-            overhead_s={f: over[f] for f in fams},
+            overhead_s={f: over[f] for f in fams}, device_kind=kind,
         ),
     )
-    for r, p_, e_, e0 in zip(measured, pred, rel, rel0):
-        r["pred_s"] = float(p_)
-        r["rel_err"] = float(e_)
-        r["rel_err_uncalibrated"] = float(e0)
+    for i, r in enumerate(measured):
+        r["pred_s"] = float(pred[i])
+        r["rel_err"] = float(rel[i])
+        r["rel_err_uncalibrated"] = None if rel0 is None else float(rel0[i])
     return {
         "rows": measured,
         "rates_flops": {f: rates[f] for f in fams},
@@ -490,7 +528,8 @@ class CalibratedBandwidth:
       (or its dict form) to any Study unwraps to this spec, so a
       JSON-round-tripped artifact reproduces bit-identical results.
     - ``efficiency``: per-family effective compute rate as a fraction
-      of ``peak_flops``. The ``'gemm'`` entry calibrates the GEMM
+      of ``peak_flops``, the measuring device's published bf16 peak
+      (``None``, with no efficiencies, for a device without one). The ``'gemm'`` entry calibrates the GEMM
       dataflows (dos/ws/is map the same MACs; ``dos_matmul`` is the
       dOS kernel) — ``efficiency_for`` exposes that mapping.
     - ``diagnostics``: fit/holdout error summary and provenance.
@@ -498,7 +537,7 @@ class CalibratedBandwidth:
 
     bandwidth: BandwidthSpec
     efficiency: dict
-    peak_flops: float
+    peak_flops: float | None
     diagnostics: dict = dataclasses.field(default_factory=dict)
 
     def __post_init__(self):
@@ -510,7 +549,8 @@ class CalibratedBandwidth:
             self, "efficiency",
             {str(k): float(v) for k, v in dict(self.efficiency).items()},
         )
-        object.__setattr__(self, "peak_flops", float(self.peak_flops))
+        if self.peak_flops is not None:
+            object.__setattr__(self, "peak_flops", float(self.peak_flops))
 
     def efficiency_for(self, dataflow: str) -> float:
         """Effective-compute factor for a GEMM dataflow (dos/os/ws/is
@@ -534,7 +574,7 @@ class CalibratedBandwidth:
         return cls(
             bandwidth=BandwidthSpec.from_dict(d["bandwidth"]),
             efficiency=d.get("efficiency", {}),
-            peak_flops=d.get("peak_flops", HW.TPU_PEAK_FLOPS_BF16),
+            peak_flops=d.get("peak_flops"),
             diagnostics=d.get("diagnostics", {}),
         )
 

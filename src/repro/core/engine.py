@@ -499,13 +499,13 @@ def _search_batch(D1, D2, Tser, budget, backend: str, chunk: int, n_shards: int 
     if B == 0:
         return r_out, c_out, t_out
     if backend == "jax":
-        from jax.experimental import enable_x64
+        import jax
 
         # One static r_max (rounded up to a power of two to bound
         # recompiles) for the whole batch keeps a single jit cache entry.
         r_max = int(np.max(np.minimum(D1, budget)))
         r_max = 1 << max(int(np.ceil(np.log2(max(r_max, 1)))), 0)
-        with enable_x64():
+        with jax.enable_x64(True):
             if n_shards > 1:
                 from ..parallel.shard_eval import sharded_search
 

@@ -541,7 +541,6 @@ def _evaluate_generation(study, stream, axes, cands, g: int, cache, workers: int
             cache.block_cells,
             [(key, cands[lo:hi].tolist()) for key, lo, hi in jobs],
             workers=workers,
-            start_method="spawn" if study.analysis.backend == "jax" else None,
         )
         for key, lo, hi in jobs:
             d = cache.peek_chunk(study, key)

@@ -414,7 +414,8 @@ class AnalysisSpec:
       batch per generation; needs a ``search`` ``SearchSpec``.
       ``workers`` (an execution knob, like backend/chunk/shard: never
       part of the cache key) farms each generation's missing cache
-      blocks to N worker processes (``parallel.work_queue``).
+      blocks to N worker processes (``parallel.work_queue``); it
+      requires ``backend='numpy'``.
     - ``'calibrate'``: measure the real kernels over ``calibrate``'s
       (a ``core.calibrate.CalibrateSpec``, defaulted when omitted)
       shape grid and fit the roofline model to the timings; the
@@ -580,6 +581,12 @@ class AnalysisSpec:
             n = int(self.workers)
             if n < 1:
                 raise ValueError(f"workers must be >= 1, got {self.workers}")
+            if n > 1 and self.backend == "jax":
+                raise ValueError(
+                    f"workers={n} needs backend='numpy': each worker process "
+                    "would need the accelerator, which one process holds at "
+                    "a time (use shard= to spread a jax study over devices)"
+                )
             object.__setattr__(self, "workers", n)
         if self.bandwidth is not None and not isinstance(self.bandwidth, BandwidthSpec):
             # A CalibratedBandwidth (or its dict form — recognizable by

@@ -27,9 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..._jax_compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
+from .._mxu import mxu_precision
 
 __all__ = ["dos_matmul_kernel", "dos_matmul_pallas"]
 
@@ -44,7 +42,8 @@ def dos_matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k_tiers: int, out_dtype
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+        precision=mxu_precision(a_ref.dtype),
     )
 
     @pl.when(k == n_k_tiers - 1)
@@ -90,7 +89,7 @@ def dos_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

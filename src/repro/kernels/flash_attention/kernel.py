@@ -10,7 +10,8 @@ the tier-to-tier accumulation rule.
 Supports causal masking, sliding-window (local) masking, GQA head
 grouping and cross-attention (no mask), so it serves every attention
 flavour in the model zoo (gemma3 local:global, whisper cross-attn,
-llama vision cross-attn, ...).
+llama vision cross-attn, ...). The window is a scalar prefetched into
+SMEM, so a scanned layer stack may pass each layer's window traced.
 """
 
 from __future__ import annotations
@@ -22,18 +23,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..._jax_compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
+from .._mxu import mxu_precision
 from .ref import NEG_INF
 
 __all__ = ["flash_attention_pallas"]
 
 _LANES = 128  # TPU vector lane width for the m/l scratch
+#: window used when none is given: wider than any sequence (global attn)
+_NO_WINDOW = 2**30
 
 
 def _attn_kernel(
+    win_ref,
     q_ref,
     k_ref,
     v_ref,
@@ -46,7 +47,7 @@ def _attn_kernel(
     bq: int,
     bk: int,
     causal: bool,
-    window: int | None,
+    kv_len: int | None,
     scale: float,
     q_offset: int,
     out_dtype,
@@ -59,18 +60,22 @@ def _attn_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale  # (bq, d)
-    k = k_ref[0].astype(jnp.float32)  # (bk, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (bq, bk)
+    # Operands enter the MXU in their own dtype; accumulation is f32.
+    q = q_ref[0]  # (bq, d)
+    prec = mxu_precision(q.dtype)
+    s = jax.lax.dot_general(
+        q, k_ref[0], (((1,), (1,)), ((), ())),  # q @ k.T
+        preferred_element_type=jnp.float32, precision=prec,
+    ) * scale  # (bq, bk)
 
     q_idx = pl.program_id(1) * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     q_idx = q_idx + q_offset
     k_idx = kv_step * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = jnp.ones((bq, bk), dtype=jnp.bool_)
+    mask = k_idx > q_idx - win_ref[0]
     if causal:
         mask = mask & (k_idx <= q_idx)
-    if window is not None:
-        mask = mask & (k_idx > q_idx - window)
+    if kv_len is not None:  # keys padded up to a block multiple
+        mask = mask & (k_idx < kv_len)
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[:, :1]  # (bq, 1)
@@ -80,9 +85,9 @@ def _attn_kernel(
     corr = jnp.exp(m_prev - m_new)  # (bq, 1)
 
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    v = v_ref[0].astype(jnp.float32)  # (bk, d)
+    v = v_ref[0]  # (bk, d)
     acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32, precision=prec
     )
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
@@ -96,7 +101,7 @@ def _attn_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "window", "scale", "q_offset", "bq", "bk", "group", "heads",
+        "causal", "scale", "q_offset", "bq", "bk", "group", "heads", "kv_len",
         "interpret",
     ),
 )
@@ -104,15 +109,16 @@ def flash_attention_pallas(
     q: jax.Array,  # (BH, Sq, D)   flattened batch*heads
     k: jax.Array,  # (BKVH, Skv, D)
     v: jax.Array,
+    window=None,  # None, an int or a traced int32 scalar (SMEM-prefetched)
     *,
     group: int,  # q heads per kv head (GQA)
     heads: int | None = None,  # q heads per batch (for kv index math)
     causal: bool = True,
-    window: int | None = None,
     scale: float | None = None,
     q_offset: int = 0,
     bq: int = 128,
     bk: int = 128,
+    kv_len: int | None = None,  # keys at or past kv_len are padding
     interpret: bool = False,
 ) -> jax.Array:
     bh, sq, d = q.shape
@@ -122,13 +128,16 @@ def flash_attention_pallas(
     assert sq % bq == 0 and skv % bk == 0, (sq, bq, skv, bk)
     if scale is None:
         scale = 1.0 / (d**0.5)
+    win = jnp.reshape(
+        jnp.asarray(_NO_WINDOW if window is None else window, jnp.int32), (1,)
+    )
     n_kv = skv // bk
     grid = (bh, sq // bq, n_kv)
 
-    def q_map(bhi, i, j):
+    def q_map(bhi, i, j, win_ref):
         return (bhi, i, 0)
 
-    def kv_map(bhi, i, j):
+    def kv_map(bhi, i, j, win_ref):
         b = bhi // h
         hh = bhi % h
         return (b * kvh + hh // group, j, 0)
@@ -139,28 +148,31 @@ def flash_attention_pallas(
         bq=bq,
         bk=bk,
         causal=causal,
-        window=window,
+        kv_len=kv_len,
         scale=scale,
         q_offset=q_offset,
         out_dtype=q.dtype,
     )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), q_map),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), q_map),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_map),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, d), kv_map),
+            ],
+            out_specs=pl.BlockSpec((1, bq, d), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v)
+    )(win, q, k, v)

@@ -73,34 +73,40 @@ def flash_attention(
             )
         interpret = False
 
-    # Pallas path: tiling parameters must be static Python values.
-    assert window is None or isinstance(window, int), (
-        "traced `window` is only supported on the jnp reference path"
-    )
+    # Pallas path: tiling parameters must be static Python values (the
+    # window may be traced: the kernel reads it from SMEM).
     assert q_offset is None or isinstance(q_offset, int)
     b, sq, h, d = q.shape
     _, skv, kvh, _ = k.shape
     group = h // kvh
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * kvh, skv, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * kvh, skv, d)
     bq = min(128, sq)
     bk = min(128, skv)
+    # Pad both sequences up to block multiples: padded queries are
+    # sliced away, padded keys are masked by kv_len.
+    sqp, skvp = -(-sq // bq) * bq, -(-skv // bk) * bk
+    if sqp != sq:
+        q = jnp.pad(q, ((0, 0), (0, sqp - sq), (0, 0), (0, 0)))
+    if skvp != skv:
+        k, v = (jnp.pad(x, ((0, 0), (0, skvp - skv), (0, 0), (0, 0))) for x in (k, v))
+    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sqp, d)
+    kf = k.transpose(0, 2, 1, 3).reshape(b * kvh, skvp, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * kvh, skvp, d)
     o = flash_attention_pallas(
         qf,
         kf,
         vf,
+        window,
         group=group,
         heads=h,
         causal=causal,
-        window=window,
         scale=scale,
         q_offset=q_offset,
         bq=bq,
         bk=bk,
+        kv_len=skv if skvp != skv else None,
         interpret=interpret,
     )
-    return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return o.reshape(b, h, sqp, d)[:, :, :sq].transpose(0, 2, 1, 3)
 
 
 def decode_attention(

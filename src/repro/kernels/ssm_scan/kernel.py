@@ -7,7 +7,8 @@ grid dim — the "tiers"); the inter-chunk SSM state (N x P) stays
 the dOS partial-sum pile. Within a chunk, the recurrence is rewritten
 as dense MXU matmuls (the SSD "matrix transform" form):
 
-  per chunk of length T, with la_i = cumsum(ld_i) (log-decay):
+  per chunk of length T, with la_i = cumsum(ld_i) (log-decay, summed
+  in XLA before the kernel):
     L_ij    = exp(la_i - la_j)  for j <= i else 0     (T x T)
     y_intra = ((C B^T) * L) @ U                        (T x P)
     y_inter = exp(la_i) * (C_i @ S_prev)               (T x P)
@@ -28,14 +29,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..._jax_compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
+from .._mxu import mxu_precision
 
 __all__ = ["ssm_scan_pallas"]
 
 
-def _ssd_kernel(u_ref, ld_ref, b_ref, c_ref, y_ref, sout_ref, s_ref, *, chunk: int, n_chunks: int):
+def _ssd_kernel(u_ref, lac_ref, lar_ref, b_ref, c_ref, y_ref, sout_ref, s_ref, *,
+                chunk: int, n_chunks: int):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
@@ -43,31 +43,32 @@ def _ssd_kernel(u_ref, ld_ref, b_ref, c_ref, y_ref, sout_ref, s_ref, *, chunk: i
         s_ref[...] = jnp.zeros_like(s_ref)
 
     u = u_ref[0].astype(jnp.float32)  # (T, P)
-    ld = ld_ref[0].astype(jnp.float32)  # (T, 1)
+    la_c = lac_ref[0]  # (T, 1) log cumulative decay, as a column
+    la_r = lar_ref[0, 0]  # (1, T) the same values, as a row
     bmat = b_ref[0].astype(jnp.float32)  # (T, N)
     cmat = c_ref[0].astype(jnp.float32)  # (T, N)
-
-    la = jnp.cumsum(ld[:, 0])  # (T,) log cumulative decay
+    f32 = dict(
+        preferred_element_type=jnp.float32, precision=mxu_precision(jnp.float32)
+    )
 
     # Intra-chunk: ((C B^T) * L) @ U with L the decay-masked lower tri.
-    cb = jnp.dot(cmat, bmat.T, preferred_element_type=jnp.float32)  # (T, T)
-    li = la[:, None] - la[None, :]  # la_i - la_j
+    cb = jnp.dot(cmat, bmat.T, **f32)  # (T, T)
+    li = la_c - la_r  # la_i - la_j
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     lmat = jnp.exp(jnp.where(jj <= ii, li, -1e30))  # mask before exp
-    y = jnp.dot(cb * lmat, u, preferred_element_type=jnp.float32)  # (T, P)
+    y = jnp.dot(cb * lmat, u, **f32)  # (T, P)
 
     # Inter-chunk: previous state decayed to each position.
     s_prev = s_ref[...]  # (N, P)
-    decay_i = jnp.exp(la)[:, None]  # (T, 1)
-    y = y + decay_i * jnp.dot(cmat, s_prev, preferred_element_type=jnp.float32)
+    y = y + jnp.exp(la_c) * jnp.dot(cmat, s_prev, **f32)
 
     # State update for the next chunk.
-    decay_tot = jnp.exp(la[-1])
-    bdec = bmat * jnp.exp(la[-1] - la)[:, None]  # (T, N)
-    s_new = decay_tot * s_prev + jnp.dot(
-        bdec.T, u, preferred_element_type=jnp.float32
-    )
+    la_last = la_r[:, chunk - 1 :]  # (1, 1)
+    bdec = bmat * jnp.exp(la_last - la_c)  # (T, N)
+    s_new = jnp.exp(la_last) * s_prev + jax.lax.dot_general(
+        bdec, u, (((0,), (0,)), ((), ())), **f32
+    )  # (N, P)
     s_ref[...] = s_new
 
     y_ref[0, ...] = y.astype(y_ref.dtype)
@@ -100,6 +101,15 @@ def ssm_scan_pallas(
     def row_map(i, j):
         return (i, 0, 0)
 
+    def chunk_row_map(i, j):
+        return (i, j, 0, 0)
+
+    # The per-chunk prefix sum of the log-decay is taken here, in XLA:
+    # Mosaic has no cumsum. The kernel reads it as a column and as one
+    # (1, chunk) row per chunk (a block equal to its array's last dims).
+    la = jnp.cumsum(
+        ld.astype(jnp.float32).reshape(bh, n_chunks, 1, chunk), axis=-1
+    )
     kernel = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=n_chunks)
     return pl.pallas_call(
         kernel,
@@ -107,6 +117,7 @@ def ssm_scan_pallas(
         in_specs=[
             pl.BlockSpec((1, chunk, p), seq_map),
             pl.BlockSpec((1, chunk, 1), seq_map),
+            pl.BlockSpec((1, 1, 1, chunk), chunk_row_map),
             pl.BlockSpec((1, chunk, n), seq_map),
             pl.BlockSpec((1, chunk, n), seq_map),
         ],
@@ -119,8 +130,8 @@ def ssm_scan_pallas(
             jax.ShapeDtypeStruct((bh, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(u, ld, B, C)
+    )(u, la.reshape(bh, s, 1), la, B, C)

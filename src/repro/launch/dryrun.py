@@ -31,7 +31,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from .._jax_compat import unwrap_cost_analysis
 from ..analysis.roofline import parse_collectives, roofline_from_artifact
 from ..config import SHAPES, RunConfig
 from ..configs import REGISTRY, cells, get_config
@@ -160,7 +159,7 @@ def lower_cell(
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = unwrap_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     coll = parse_collectives(compiled.as_text())
     rf = roofline_from_artifact(
         arch=arch,

@@ -86,6 +86,7 @@ def serve_loop(
     steps = jnp.asarray(step_s) if step_s else jnp.zeros(1)
     return {
         "generated": gen,
+        "last_logits": logits,
         "prefill_s": t_prefill,
         "decode_s": t_decode,
         "decode_tok_s": batch * (gen_tokens - 1) / max(t_decode, 1e-9),

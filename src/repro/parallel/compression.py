@@ -24,8 +24,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .._jax_compat import shard_map as _shard_map
-
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["init_error_state", "compressed_psum_grads"]
@@ -63,7 +61,7 @@ def compressed_psum_grads(grads, error_state, mesh, axis: str = "data"):
 
     def leaf_sync(g, err):
         @functools.partial(
-            _shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+            jax.shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         )
         def f(g_, e_):
             return one_sync(g_, e_)

@@ -29,8 +29,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .._jax_compat import shard_map as _shard_map
-
 from jax.sharding import PartitionSpec as P
 
 from ..models.layers import proj
@@ -60,7 +58,7 @@ def moe_block_ep(p, x, cfg, mesh, *, axis: str = "model", batch_axis: str | None
     )
 
     @functools.partial(
-        _shard_map, mesh=mesh, in_specs=in_specs, out_specs=P(batch_axis),
+        jax.shard_map, mesh=mesh, in_specs=in_specs, out_specs=P(batch_axis),
     )
     def run(pl, xl):
         b, s, e = xl.shape

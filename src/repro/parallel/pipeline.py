@@ -9,11 +9,7 @@ jax.grad differentiates straight through it (ppermute's transpose is
 the reverse permute, giving the backward pipeline for free).
 
 Embedding runs on stage 0, the LM head + loss on the last stage. The
-loop is written version-agnostically so it runs on jax 0.4 and >= 0.7
-alike: every value carried through the shard_map body has rank >= 1
-(jax 0.4's linearization names shard_map residuals ``{0: axes}``,
-which a rank-0 carry cannot satisfy, breaking the backward pass), and
-the loss leaves the body as a per-stage ``P(stage_axis)`` output
+loss leaves the body as a rank-1 per-stage ``P(stage_axis)`` output
 summed *outside* — only the last stage contributes a nonzero partial,
 so no in-body psum/broadcast collective is needed at all. Bubble
 fraction is (n_stages - 1) / (n_mb + n_stages - 1) — the §Perf log
@@ -30,9 +26,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from .._jax_compat import pcast as _pcast
-from .._jax_compat import shard_map as _shard_map
 
 from jax.sharding import PartitionSpec as P
 
@@ -90,7 +83,7 @@ def make_gpipe_loss(cfg, mesh, *, n_stages: int, n_microbatches: int,
         other_axes = tuple(a for a in mesh.axis_names if a != stage_axis)
 
         @functools.partial(
-            _shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(
                 P(stage_axis),  # layers: stage dim sharded
@@ -112,7 +105,6 @@ def make_gpipe_loss(cfg, mesh, *, n_stages: int, n_microbatches: int,
             n_ticks = n_mb + n_stages - 1
             compute_dtype = jnp.dtype(cfg.compute_dtype)
             act0 = jnp.zeros((mb, s, cfg.d_model), compute_dtype)
-            # rank >= 1 keeps jax 0.4's residual naming representable
             loss0 = jnp.zeros((1,), jnp.float32)
             fwd_perm = [(i, i + 1) for i in range(n_stages - 1)]
 
@@ -139,8 +131,8 @@ def make_gpipe_loss(cfg, mesh, *, n_stages: int, n_microbatches: int,
                 return (act_next, loss_sum), None
 
             # carries become stage-varying after my-dependent selects
-            act0_v = _pcast(act0, (stage_axis,), to="varying")
-            loss0_v = _pcast(loss0, (stage_axis,), to="varying")
+            act0_v = jax.lax.pcast(act0, (stage_axis,), to="varying")
+            loss0_v = jax.lax.pcast(loss0, (stage_axis,), to="varying")
             (_, loss_sum), _ = jax.lax.scan(
                 tick, (act0_v, loss0_v), jnp.arange(n_ticks)
             )

@@ -64,7 +64,7 @@ def _sharded_search_fn(n_shards: int, r_max_total: int):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from .._jax_compat import make_mesh, shard_map
+    from .._jax_compat import make_mesh
     from ..core.analytical import _search_rc
 
     mesh = make_mesh((n_shards,), ("shard",))
@@ -72,7 +72,7 @@ def _sharded_search_fn(n_shards: int, r_max_total: int):
     def search(D1, D2, Tser, budget):
         return _search_rc(jnp, D1, D2, Tser, budget, r_max_total)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         search,
         mesh=mesh,
         in_specs=(P("shard"),) * 4,

@@ -15,15 +15,12 @@ is deterministic and JSON float64 round-trips are exact), which is why
 ``AnalysisSpec.workers`` is an execution knob excluded from the spec
 hash — a sweep started with one worker resumes with eight.
 
-Inside each worker the engine's own parallelism still applies: a
-``shard='auto'`` study shards its (R, C) search over the worker's local
-JAX devices (``parallel.shard_eval``), composing process-level and
-device-level parallelism.
+Workers run numpy-backend studies only: a device belongs to one
+process, so N workers of a jax study would contend for one chip, and
+``AnalysisSpec`` rejects ``backend='jax'`` with ``workers > 1``.
 
 Start method: ``fork`` where available (cheap, inherits sys.path), else
-``spawn``. Callers using the jax backend should pass
-``start_method='spawn'`` — forking a process after jax initializes its
-thread pools is unsafe.
+``spawn``.
 """
 
 from __future__ import annotations
@@ -63,7 +60,7 @@ def _ensure_importable() -> None:
 
 
 def run_blocks(study_json: str, cache_root: str, block_cells: int, jobs,
-               workers: int, start_method: str | None = None) -> list[str]:
+               workers: int) -> list[str]:
     """Farm ``jobs`` = [(chunk_key, candidate_rows), ...] to N processes.
 
     Blocks until every chunk is stored (or re-raises the first worker
@@ -72,9 +69,8 @@ def run_blocks(study_json: str, cache_root: str, block_cells: int, jobs,
     jobs = list(jobs)
     if not jobs:
         return []
-    if start_method is None:
-        methods = multiprocessing.get_all_start_methods()
-        start_method = "fork" if "fork" in methods else "spawn"
+    methods = multiprocessing.get_all_start_methods()
+    start_method = "fork" if "fork" in methods else "spawn"
     if start_method == "spawn":
         _ensure_importable()
     ctx = multiprocessing.get_context(start_method)

@@ -7,10 +7,12 @@ rows so the suite stays timing-independent.
 
 import json
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core.calibrate import (
+    DEVICE_PEAKS,
     CalibrateSpec,
     CalibratedBandwidth,
     fit_rows,
@@ -80,7 +82,7 @@ def _synthetic_rows(rates, bw, overhead, noise=0.0, seed=0):
         d = dict(r)
         d.update(t_s=t, spread_s=0.0, reps=1,
                  achieved_gflops=r["flops"] / t / 1e9,
-                 achieved_gbs=r["bytes"] / t / 1e9)
+                 achieved_gbs=r["bytes"] / t / 1e9, device_kind="TPU v5 lite")
         rows.append(d)
     return spec, rows
 
@@ -118,12 +120,43 @@ def test_run_calibration_accepts_premeasured_rows():
     assert p1["artifact"].to_dict() == p2["artifact"].to_dict()  # deterministic
 
 
+def test_fit_peaks_follow_the_measuring_device():
+    rates = {"gemm": 1e11, "attention": 2e10, "ssm": 4e10}
+    spec, rows = _synthetic_rows(rates, 3e9, {f: 0.0 for f in rates})
+    p = fit_rows(rows, spec)
+    art = p["artifact"]
+    assert art.diagnostics["device_kind"] == "TPU v5 lite"
+    assert art.peak_flops == DEVICE_PEAKS["TPU v5 lite"][0]
+    assert p["efficiency"]["gemm"] == pytest.approx(
+        p["rates_flops"]["gemm"] / art.peak_flops)
+    # the host CPU has no published peak: rates and errors, no efficiency
+    pc = fit_rows([dict(r, device_kind="cpu") for r in rows], spec)
+    assert pc["efficiency"] == {} and pc["artifact"].peak_flops is None
+    assert pc["errors"]["uncalibrated_holdout_median_rel_err"] is None
+    assert pc["rates_flops"] == p["rates_flops"]
+    d = json.loads(json.dumps(pc["artifact"].to_dict(), allow_nan=False))
+    assert CalibratedBandwidth.from_dict(d) == pc["artifact"]
+
+
+@pytest.mark.parametrize(
+    "kinds", [("TPU v9 unknown",), ("TPU v5 lite", "cpu"), (None,)],
+    ids=["unknown", "mixed", "unrecorded"],
+)
+def test_fit_rejects_unknown_or_mixed_devices(kinds):
+    rates = {"gemm": 1e11, "attention": 2e10, "ssm": 4e10}
+    spec, rows = _synthetic_rows(rates, 3e9, {f: 0.0 for f in rates})
+    rows = [dict(r, device_kind=kinds[i % len(kinds)]) for i, r in enumerate(rows)]
+    with pytest.raises(ValueError, match="device"):
+        fit_rows(rows, spec)
+
+
 def test_measure_row_smoke():
     """One real (tiny) measurement: JSON-safe and self-consistent."""
     row = next(r for r in shape_grid(CalibrateSpec(preset="smoke"))
                if r["family"] == "gemm")
     d = measure_row(row, reps=1, warmup=1)
     json.dumps(d, allow_nan=False)  # strict-JSON safe
+    assert d["device_kind"] == jax.devices()[0].device_kind
     assert d["t_s"] > 0 and d["achieved_gflops"] > 0
     assert d["achieved_gflops"] == pytest.approx(
         d["flops"] / d["t_s"] / 1e9)
@@ -204,7 +237,7 @@ def _fake_measure(row, *, reps=5, warmup=2, seed=0):
     d = dict(row)
     d.update(t_s=t, spread_s=0.0, reps=reps,
              achieved_gflops=row["flops"] / t / 1e9,
-             achieved_gbs=row["bytes"] / t / 1e9)
+             achieved_gbs=row["bytes"] / t / 1e9, device_kind="TPU v5 lite")
     return d
 
 

@@ -39,6 +39,36 @@ def test_pallas_kernel_interpret(case, dtype):
     np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("case", [
+    (1, 200, 200, 4, 2, 64, True, None),  # causal, not a block multiple
+    (2, 136, 300, 2, 2, 32, False, None),  # cross: padded keys masked
+    (1, 200, 200, 2, 2, 64, True, 48),  # sliding window across padding
+])
+def test_pallas_kernel_interpret_padded_lengths(case):
+    b, sq, skv, h, kvh, d, causal, window = case
+    q, k, v = _mk(b, sq, skv, h, kvh, d, "float32", seed=4)
+    ref = np.asarray(attention_ref(q, k, v, causal=causal, window=window))
+    out = np.asarray(
+        flash_attention(q, k, v, causal=causal, window=window, interpret=True)
+    )
+    assert out.shape == ref.shape
+    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
+
+
+def test_pallas_kernel_traced_window():
+    """Scanned layer stacks pass each layer's window traced; the kernel
+    reads it from SMEM and matches the static-window reference."""
+    q, k, v = _mk(1, 256, 256, 2, 2, 64, "float32", seed=6)
+    run = jax.jit(
+        lambda w: flash_attention(q, k, v, causal=True, window=w, interpret=True)
+    )
+    for w in (64, 2**30):
+        ref = attention_ref(q, k, v, causal=True, window=w)
+        np.testing.assert_allclose(
+            np.asarray(run(jnp.int32(w))), np.asarray(ref), rtol=1e-4, atol=1e-4
+        )
+
+
 @pytest.mark.parametrize("case", CASES)
 def test_chunked_jnp_forward_and_grads(case):
     b, sq, skv, h, kvh, d, causal, window = case

@@ -92,6 +92,27 @@ def test_workers_is_not_part_of_the_spec_hash():
     assert study_hash(a) == study_hash(b)
 
 
+def test_jax_backend_rejects_worker_processes(tmp_path):
+    """One accelerator belongs to one process: N workers of a jax study
+    would all need it, so the spec refuses the pair up front."""
+    study = _study()
+    jax_analysis = dataclasses.replace(study.analysis, backend="jax")
+    assert jax_analysis.workers is None
+    for n in (None, 1):
+        dataclasses.replace(jax_analysis, workers=n)  # one process: fine
+    with pytest.raises(ValueError, match="workers=2 needs backend='numpy'"):
+        dataclasses.replace(jax_analysis, workers=2)
+    # the CLI's --workers override reports it as an error, not a traceback
+    spec = tmp_path / "spec.json"
+    spec.write_text(dataclasses.replace(study, analysis=jax_analysis).to_json())
+    out = subprocess.run(
+        [sys.executable, "-m", "repro", "run", str(spec), "--workers", "2"],
+        capture_output=True, text=True,
+    )
+    assert out.returncode != 0
+    assert "error: --workers 2" in out.stderr and "Traceback" not in out.stderr
+
+
 def test_search_requires_bandwidth_for_memory_axes():
     with pytest.raises(ValueError, match="bandwidth"):
         Study(

@@ -426,6 +426,28 @@ def test_cli_example_spec_and_stdin_run(tmp_path, capsys, monkeypatch):
     assert len(art.payload["names"]) == 2
 
 
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the only cache directory;
+    otherwise the cache sits at a fixed .jax_cache/ in the repo root."""
+    import jax
+
+    from repro._jax_compat import REPO_COMPILE_CACHE, use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None  # set no other
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == str(REPO_COMPILE_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_COMPILE_CACHE)
+        assert REPO_COMPILE_CACHE.name == ".jax_cache"
+        assert (REPO_COMPILE_CACHE.parent / "pyproject.toml").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_cli_rejects_bad_spec(tmp_path):
     from repro.cli import main
 
