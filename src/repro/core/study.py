@@ -41,6 +41,7 @@ import math
 import pathlib
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import calibrate as _calibrate
 from .bandwidth import BOUND_NAMES, BandwidthSpec
@@ -749,26 +750,28 @@ class Study:
         it left off (``python -m repro run --resume``). The returned
         ``StudyResult.cache`` carries the hit/miss counters.
         """
-        if cache is not None and not isinstance(cache, ResultCache):
-            cache = ResultCache(cache)
-        stream = self.workload.resolve()
-        runner = getattr(self, f"_run_{self.analysis.kind}")
-        if cache is None:
-            payload = runner(stream)
-            return StudyResult(study=self, kind=self.analysis.kind, payload=payload)
-        cache.prepare(self)
-        h0, m0 = cache.hits, cache.misses  # shared caches: report this run only
-        payload = runner(stream, cache=cache)
-        stats = dict(cache.stats())
-        stats["hits"] -= h0
-        stats["misses"] -= m0
-        stats["chunks"] = stats["hits"] + stats["misses"]
-        result = StudyResult(
-            study=self, kind=self.analysis.kind, payload=payload,
-            cache=stats,
-        )
-        cache.store_result(self, result)
-        return result
+        with TraceAnnotation("repro.study", kind=self.analysis.kind):
+            if cache is not None and not isinstance(cache, ResultCache):
+                cache = ResultCache(cache)
+            with TraceAnnotation("repro.lower"):
+                stream = self.workload.resolve()
+            runner = getattr(self, f"_run_{self.analysis.kind}")
+            if cache is None:
+                payload = runner(stream)
+                return StudyResult(study=self, kind=self.analysis.kind, payload=payload)
+            cache.prepare(self)
+            h0, m0 = cache.hits, cache.misses  # shared caches: report this run only
+            payload = runner(stream, cache=cache)
+            stats = dict(cache.stats())
+            stats["hits"] -= h0
+            stats["misses"] -= m0
+            stats["chunks"] = stats["hits"] + stats["misses"]
+            result = StudyResult(
+                study=self, kind=self.analysis.kind, payload=payload,
+                cache=stats,
+            )
+            cache.store_result(self, result)
+            return result
 
     def _chunk_for(self, workloads) -> int | None:
         a = self.analysis

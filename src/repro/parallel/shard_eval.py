@@ -19,6 +19,7 @@ from __future__ import annotations
 import functools
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = ["resolve_shards", "sharded_search"]
 
@@ -69,11 +70,11 @@ def _sharded_search_fn(n_shards: int, r_max_total: int):
 
     mesh = make_mesh((n_shards,), ("shard",))
 
-    def search(D1, D2, Tser, budget):
+    def sharded_search_rc(D1, D2, Tser, budget):
         return _search_rc(jnp, D1, D2, Tser, budget, r_max_total)
 
     fn = jax.shard_map(
-        search,
+        sharded_search_rc,
         mesh=mesh,
         in_specs=(P("shard"),) * 4,
         out_specs=(P("shard"),) * 3,
@@ -99,8 +100,9 @@ def sharded_search(D1, D2, Tser, budget, r_max_total: int, n_shards: int):
         )
     fn = _sharded_search_fn(n_shards, r_max_total)
     r, c, t = fn(D1, D2, Tser, budget)
-    return (
-        np.asarray(r)[:B],
-        np.asarray(c)[:B],
-        np.asarray(t)[:B],
-    )
+    with TraceAnnotation("repro.search.fetch", rows=B):
+        return (
+            np.asarray(r)[:B],
+            np.asarray(c)[:B],
+            np.asarray(t)[:B],
+        )
