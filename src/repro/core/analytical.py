@@ -350,14 +350,14 @@ def optimize_rc_batched(
     this function alike. ``backend`` selects numpy or the jitted JAX
     search kernel.
     """
-    from .engine import _DEFAULT_CHUNK, _optimize_flat  # lazy: engine imports us
+    from .engine import _optimize_flat  # lazy: engine imports us
 
     M, K, N, n_macs, L = np.broadcast_arrays(
         *(np.asarray(x, dtype=np.int64) for x in (M, K, N, n_macs, tiers))
     )
     shape = M.shape
     flat = [np.ascontiguousarray(x.reshape(-1)) for x in (M, K, N, n_macs, L)]
-    r, c, t = _optimize_flat(*flat, dataflow, mode, backend, _DEFAULT_CHUNK)
+    r, c, t = _optimize_flat(*flat, dataflow, mode, backend, None)
     return r.reshape(shape), c.reshape(shape), t.reshape(shape)
 
 

@@ -103,7 +103,12 @@ __all__ = [
     "ICI_HOP_LATENCY_S",
 ]
 
-_DEFAULT_CHUNK = 2048
+#: candidates (rows x searched width) a search launch holds under
+#: ``chunk=None``: the rows per launch follow from the width searched.
+_LAUNCH_CANDIDATES = 1 << 23
+#: the numpy backend pays nothing per launch and is bound by host
+#: memory, so under ``chunk=None`` its chunks keep 64..2048 rows.
+_NUMPY_ROWS = (64, 2048)
 _ALL_METRICS = ("perf", "area", "power", "thermal")
 #: evaluate() streams point-blocks once the (W, P) result matrix would
 #: exceed this many cells — bounds peak memory at any grid size.
@@ -485,13 +490,19 @@ def _jax_search_fn(r_max_total: int):
     return jax.jit(search_rc)
 
 
-def _search_batch(D1, D2, Tser, budget, backend: str, chunk: int, n_shards: int = 1):
+def _search_batch(D1, D2, Tser, budget, backend: str, chunk: int | None,
+                  n_shards: int = 1):
     """Chunked dispatch of the (R, C) search. Returns (r, c, tau) int64.
 
-    ``n_shards`` > 1 (jax backend) splits each chunk across the local
-    JAX devices via ``parallel.shard_eval`` — same kernel, same static
-    search width, so results match the unsharded path bit-for-bit. The
-    numpy backend has no device axis and ignores ``n_shards``.
+    ``chunk`` is rows per device launch; None derives it from the width
+    the batch searches, ~2^23 candidates a launch (numpy: 64..2048
+    rows). Results never depend on it.
+
+    ``n_shards`` > 1 (jax backend) splits each launch across the local
+    JAX devices via ``parallel.shard_eval``, ``chunk`` rows per device —
+    same kernel, same static search width, so results match the
+    unsharded path bit-for-bit. The numpy backend has no device axis
+    and ignores ``n_shards``.
     """
     B = D1.shape[0]
     with TraceAnnotation("repro.search", rows=B) as span:
@@ -507,7 +518,8 @@ def _search_batch(D1, D2, Tser, budget, backend: str, chunk: int, n_shards: int 
             # recompiles) for the whole batch keeps a single jit cache entry.
             r_max = int(np.max(np.minimum(D1, budget)))
             r_max = 1 << max(int(np.ceil(np.log2(max(r_max, 1)))), 0)
-            step = chunk * n_shards  # ~chunk rows per device when sharded
+            rows = chunk if chunk is not None else max(1, _LAUNCH_CANDIDATES // r_max)
+            step = rows * n_shards  # rows per device when sharded
             span.set_metadata(width=r_max, launches=-(-B // step))
             with jax.enable_x64(True):
                 if n_shards > 1:
@@ -522,8 +534,8 @@ def _search_batch(D1, D2, Tser, budget, backend: str, chunk: int, n_shards: int 
                         r_out[lo:hi], c_out[lo:hi], t_out[lo:hi] = r, c, t
                     return r_out, c_out, t_out
                 fn = _jax_search_fn(r_max)
-                for lo in range(0, B, chunk):
-                    hi = min(lo + chunk, B)
+                for lo in range(0, B, rows):
+                    hi = min(lo + rows, B)
                     r, c, t = fn(D1[lo:hi], D2[lo:hi], Tser[lo:hi], budget[lo:hi])
                     with TraceAnnotation("repro.search.fetch", rows=hi - lo):
                         r_out[lo:hi], c_out[lo:hi], t_out[lo:hi] = (
@@ -539,10 +551,13 @@ def _search_batch(D1, D2, Tser, budget, backend: str, chunk: int, n_shards: int 
         widths = np.minimum(D1, budget)
         order = np.argsort(widths, kind="stable")
         r_max_total = int(widths[order[-1]])
-        span.set_metadata(width=r_max_total, launches=-(-B // chunk))
+        rows = chunk if chunk is not None else int(
+            np.clip(_LAUNCH_CANDIDATES // max(r_max_total, 1), *_NUMPY_ROWS)
+        )
+        span.set_metadata(width=r_max_total, launches=-(-B // rows))
         tables = _factored_tables(D1, D2, budget, r_max_total)
-        for lo in range(0, B, chunk):
-            sel = order[lo : lo + chunk]
+        for lo in range(0, B, rows):
+            sel = order[lo : lo + rows]
             r_max = int(widths[sel[-1]])
             r = c = t = None
             if tables is not None:
@@ -666,7 +681,7 @@ def evaluate(
     grid: DesignGrid,
     backend: str = "numpy",
     metrics: Sequence[str] = _ALL_METRICS,
-    chunk: int = _DEFAULT_CHUNK,
+    chunk: int | None = None,
     thermal_limit: float = C.THERMAL_BUDGET_C,
     shard: int | str | None = None,
     stream: int | None = None,
@@ -680,8 +695,9 @@ def evaluate(
 
     ``metrics`` selects result groups: 'perf' (always computed),
     'area', 'power', 'thermal' (thermal implies power implies area).
-    ``chunk`` bounds the working-set of the (B, R_max) search
-    intermediates; results are independent of it. ``thermal_limit``
+    ``chunk`` is rows per device launch of the (R, C) search; ``None``
+    derives it from the searched width (2^23 candidates a launch; numpy
+    capped at 2048 rows); it never changes results. ``thermal_limit``
     sets the junction temperature [degC] behind
     ``within_thermal_budget`` / ``feasible``.
 
@@ -775,7 +791,7 @@ def _evaluate_block(
     grid: DesignGrid,
     backend: str,
     metrics: set,
-    chunk: int,
+    chunk: int | None,
     thermal_limit: float,
     n_shards: int = 1,
     bandwidth: BandwidthSpec | None = None,
@@ -1075,7 +1091,7 @@ def optimal_tiers_batched(
     max_tiers: int = 16,
     mode: str = "opt",
     backend: str = "numpy",
-    chunk: int = _DEFAULT_CHUNK,
+    chunk: int | None = None,
     shard: int | str | None = None,
     tech: str = "tsv",
     bandwidth: BandwidthSpec | dict | None = None,
@@ -1228,18 +1244,6 @@ class NetworkReport:
         return cls(**kw)
 
 
-def _adaptive_chunk(workloads, mac_budgets) -> int:
-    """Bound the (chunk, r_max) search working set to ~2^23 elements.
-
-    Network streams carry token-sized dims (M up to tens of
-    thousands), so the default 2048-wide chunks would allocate
-    multi-GB tau intermediates. Results are chunk-independent."""
-    wl = np.atleast_2d(np.asarray(workloads, dtype=np.int64))
-    d1_max = int(wl.max())  # upper bound on D1 for any dataflow
-    r_max = min(d1_max, int(np.max(mac_budgets)))
-    return int(np.clip((1 << 23) // max(r_max, 1), 64, _DEFAULT_CHUNK))
-
-
 def thermal_feasible(
     workloads,
     mac_budgets,
@@ -1258,9 +1262,7 @@ def thermal_feasible(
         dataflow=dataflow, tech=tech,
     )
     res = evaluate(
-        grid, backend=backend, metrics=("thermal",),
-        chunk=_adaptive_chunk(wl, grid.mac_budgets),
-        thermal_limit=thermal_limit,
+        grid, backend=backend, metrics=("thermal",), thermal_limit=thermal_limit,
     )
     return res.feasible
 
@@ -1534,8 +1536,6 @@ def schedule(
     W = wl.shape[0]
     if counts.shape != (W,):
         raise ValueError(f"counts shape {counts.shape} != ({W},)")
-    if chunk is None:
-        chunk = _adaptive_chunk(wl, mac_budgets)
 
     # Pass 1: per-layer optimal shapes over the (budget x tier) grid —
     # only the searched (rows, cols) feed the candidate set, so skip

@@ -251,7 +251,7 @@ def evaluate_candidates(study, cands, stream=None, axes=None):
         thermal_limit=study.constraints.thermal_limit_c,
         shard=a.shard,
         bandwidth=a.bandwidth,
-        **({"chunk": a.chunk} if a.chunk is not None else {}),
+        chunk=a.chunk,
     )
     mask = study.constraints.mask(res)
     feasible = mask.all(axis=0)

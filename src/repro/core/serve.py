@@ -290,9 +290,8 @@ def _eval_kw(study, bandwidth) -> dict:
         thermal_limit=study.constraints.thermal_limit_c,
         shard=study.analysis.shard,
         bandwidth=bandwidth,
+        chunk=study.analysis.chunk,
     )
-    if study.analysis.chunk is not None:
-        kw["chunk"] = study.analysis.chunk
     return kw
 
 

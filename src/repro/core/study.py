@@ -52,7 +52,6 @@ from .engine import (
     DesignGrid,
     EvalResult,
     NetworkReport,
-    _adaptive_chunk,
     evaluate,
     optimal_tiers_batched,
     schedule,
@@ -462,8 +461,9 @@ class AnalysisSpec:
     also price the fine-grain tier-folded mapping (each layer's GEMM
     partitioned across tiers along its best dimension, vlink-priced).
 
-    ``chunk=None`` uses the engine default, except for network
-    workloads where the adaptive bound kicks in (token-sized M dims).
+    ``chunk`` is rows per device launch of the (R, C) search; ``None``
+    derives it from the searched width (2^23 candidates a launch; numpy
+    capped at 2048 rows); it never changes results.
     ``shard`` is the engine's device-sharding knob (``'auto'`` = split
     the search over all local JAX devices; results are unchanged).
     """
@@ -773,22 +773,9 @@ class Study:
             cache.store_result(self, result)
             return result
 
-    def _chunk_for(self, workloads) -> int | None:
-        a = self.analysis
-        if a.chunk is not None:
-            return a.chunk
-        if self.workload.kind == "network" and self.space.mac_budgets is not None:
-            # token-sized M dims: bound the search working set like
-            # engine.schedule does (results are chunk-independent).
-            return _adaptive_chunk(workloads, self.space.mac_budgets)
-        return None
-
     def _evaluate(self, stream, metrics=None, cache: ResultCache | None = None) -> EvalResult:
         grid = self.space.to_grid(stream.workloads)
-        kw = {}
-        chunk = self._chunk_for(stream.workloads)
-        if chunk is not None:
-            kw["chunk"] = chunk
+        kw = {"chunk": self.analysis.chunk}
         kw["backend"] = self.analysis.backend
         kw["metrics"] = self.analysis.metrics if metrics is None else metrics
         kw["thermal_limit"] = self.constraints.thermal_limit_c
@@ -917,8 +904,6 @@ class Study:
             if d is not None:
                 return _restore_payload("schedule", d)
         kw = {}
-        if self.analysis.chunk is not None:
-            kw["chunk"] = self.analysis.chunk
         if self.analysis.policies is not None:
             kw["policies"] = self.analysis.policies
         rep = schedule(
@@ -930,6 +915,7 @@ class Study:
             backend=self.analysis.backend,
             thermal_limit=self.constraints.thermal_limit_c,
             require_feasible=self.constraints.require_feasible,
+            chunk=self.analysis.chunk,
             shard=self.analysis.shard,
             bandwidth=self.analysis.bandwidth,
             thermal=self.analysis.thermal,
@@ -1030,7 +1016,8 @@ class Study:
         Fig-7-style million-point sweeps (``benchmarks/scale_bench.py``).
         """
         kw = dict(max_tiers=max_tiers, mode=self.space.mode,
-                  backend=self.analysis.backend, shard=self.analysis.shard)
+                  backend=self.analysis.backend, chunk=self.analysis.chunk,
+                  shard=self.analysis.shard)
         if self.analysis.bandwidth is not None:
             if not isinstance(self.space.tech, str):
                 raise ValueError(

@@ -100,8 +100,8 @@ def test_ssm_scan_compiles_for_v5e(one_chip):
 
 def test_search_rc_compiles_for_v5e(one_chip):
     """The engine's jitted (R, C) search at its widest static width and
-    the adaptive chunk's floor of 64 rows: int64, which the chip
-    emulates, must still fit the chip."""
+    64 rows, twice the 2**23 // width rows a launch holds there: int64,
+    which the chip emulates, must still fit the chip."""
     rows = jax.ShapeDtypeStruct((64,), jnp.int64, sharding=one_chip)
     with jax.enable_x64(True):
         compiled = _jax_search_fn(1 << 18).lower(rows, rows, rows, rows).compile()

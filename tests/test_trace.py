@@ -23,6 +23,7 @@ from repro.core.study import Study
 from repro.parallel import shard_eval
 
 CHUNK = 16  # small, so every search takes several launches
+LAUNCH_CANDIDATES = 1 << 23  # rows x width a launch holds without a chunk
 
 SCHEDULE = {
     "workload": {"kind": "network", "arch": "smollm-135m", "shape": "decode_32k"},
@@ -38,6 +39,9 @@ FIG7 = {
     "analysis": {"kind": "sweep", "figure": "fig7", "backend": "jax",
                  "chunk": CHUNK},
 }
+DERIVED = {name: {**spec, "analysis": {k: v for k, v in spec["analysis"].items()
+                                      if k != "chunk"}}
+           for name, spec in (("schedule", SCHEDULE), ("fig7", FIG7))}
 NAMES = ("repro.study", "repro.lower", "repro.evaluate", "repro.search",
          "repro.search.fetch", "repro.price", "repro.thermal", "repro.select")
 
@@ -103,6 +107,18 @@ def fig7_run(tmp_path_factory):
         return _traced(FIG7, tmp_path_factory.mktemp("fig7"), mp)
 
 
+@pytest.fixture(scope="module")
+def schedule_derived_run(tmp_path_factory):
+    with pytest.MonkeyPatch.context() as mp:
+        return _traced(DERIVED["schedule"], tmp_path_factory.mktemp("schedule_d"), mp)
+
+
+@pytest.fixture(scope="module")
+def fig7_derived_run(tmp_path_factory):
+    with pytest.MonkeyPatch.context() as mp:
+        return _traced(DERIVED["fig7"], tmp_path_factory.mktemp("fig7_d"), mp)
+
+
 def _of(spans, name):
     return [s for s in spans if s[2] == name]
 
@@ -151,22 +167,37 @@ def test_fig7_spans_nest_as_the_layers(fig7_run):
             _parent(s, spans, "repro.study")
 
 
-@pytest.mark.parametrize("run", ["schedule_run", "fig7_run"])
+def _geometry(D1, budget, chunk):
+    """(width, rows per launch) of one search: the power of two the
+    batch searches, and ``chunk`` or else 2**23 candidates a launch."""
+    width = 1 << max(math.ceil(math.log2(max(int(np.max(np.minimum(D1, budget))), 1))), 0)
+    return width, chunk if chunk is not None else LAUNCH_CANDIDATES // width
+
+
+def _launches(calls):
+    return sum(-(-D1.shape[0] // _geometry(D1, budget, chunk)[1])
+               for D1, budget, chunk in calls)
+
+
+RUNS = ["schedule_run", "fig7_run", "schedule_derived_run", "fig7_derived_run"]
+
+
+@pytest.mark.parametrize("run", RUNS)
 def test_search_spans_carry_the_batch_searched(run, request):
     _, _, spans, calls = request.getfixturevalue(run)
     searches = _of(spans, "repro.search")
     assert len(searches) == len(calls) > 0
+    assert all((chunk is None) == ("derived" in run) for _, _, chunk in calls)
     for span, (D1, budget, chunk) in zip(searches, calls):
         B = D1.shape[0]
-        width = 1 << max(math.ceil(math.log2(max(int(np.max(np.minimum(D1, budget))), 1))), 0)
-        launches = -(-B // chunk)
+        width, rows = _geometry(D1, budget, chunk)
+        launches = -(-B // rows)
         assert span[3] == {"rows": B, "width": width, "launches": launches}
         fetches = [f for f in _of(spans, "repro.search.fetch") if _inside(f, span)]
-        assert len(fetches) == launches  # one fetch per chunk
+        assert len(fetches) == launches  # one fetch per launch
         assert [f[3]["rows"] for f in fetches] == [
-            min(chunk, B - lo) for lo in range(0, B, chunk)]
-    assert len(_of(spans, "repro.search.fetch")) == sum(
-        -(-D1.shape[0] // chunk) for D1, _, chunk in calls)
+            min(rows, B - lo) for lo in range(0, B, rows)]
+    assert len(_of(spans, "repro.search.fetch")) == _launches(calls)
 
 
 def test_schedule_select_counts_the_candidates(schedule_run):
@@ -175,10 +206,18 @@ def test_schedule_select_counts_the_candidates(schedule_run):
     assert first[3] == {"candidates": json.loads(traced)["report"]["n_candidates"]}
 
 
-@pytest.mark.parametrize("run", ["schedule_run", "fig7_run"])
+@pytest.mark.parametrize("run", RUNS)
 def test_tracing_leaves_the_payload_alone(run, request):
     untraced, traced, _, _ = request.getfixturevalue(run)
     assert traced == untraced
+
+
+@pytest.mark.parametrize("kind", ["schedule", "fig7"])
+def test_launch_geometry_leaves_the_payload_alone(kind, request):
+    chunked, _, _, calls = request.getfixturevalue(f"{kind}_run")
+    derived, _, _, derived_calls = request.getfixturevalue(f"{kind}_derived_run")
+    assert _launches(calls) > _launches(derived_calls)
+    assert chunked == derived
 
 
 def test_search_programs_have_stable_names():
