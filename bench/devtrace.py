@@ -7,13 +7,15 @@ event per program execution (a launch). The host plane ``/host:CPU``
 has a line per thread; the thread that ran the benchmark holds its own
 annotations, ``bench.window`` around the measured window and
 ``bench.study`` around each study, nested with the Python calls and
-runtime events of that thread. All times are nanoseconds on the
-profiler's one clock.
+runtime events of that thread. An event's stats (a span's keyword
+arguments) are kept beside it in ``Trace.args``. All times are
+nanoseconds on the profiler's one clock.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import warnings
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
@@ -34,6 +36,9 @@ class Trace:
     studies: list  # (start_ns, end_ns) of each bench.study
     devices: list  # Device, in device order
     host: list  # (start_ns, end_ns, name) of each event of the benchmark's thread
+    #: the arguments of each event of ``host``, in the same order: a span's
+    #: keyword arguments (``rows``, ``width``, ...) as {name: value}
+    args: list = dataclasses.field(default_factory=list)
 
     @property
     def window_ns(self) -> float:
@@ -52,7 +57,7 @@ def op_name(name: str) -> str:
 
 def from_profile(profile) -> Trace:
     """Build a ``Trace`` from a ``jax.profiler.ProfileData``."""
-    devices, host = [], []
+    devices, host, args = [], [], []
     for plane in profile.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             lines = {ln.name: ln for ln in plane.lines}
@@ -66,12 +71,18 @@ def from_profile(profile) -> Trace:
                 events = _events(ln)
                 if any(n == "bench.window" for _, _, n in events):
                     host.extend(events)
+                    with warnings.catch_warnings():
+                        # the binding's stats type warns on first use; where
+                        # warnings are errors, that aborts the process
+                        warnings.filterwarnings(
+                            "ignore", "builtin type event_stats", DeprecationWarning)
+                        args.extend(dict(e.stats) for e in ln.events)
     devices.sort(key=lambda d: int(d.name[len(DEVICE_PREFIX):].split()[0]))
     windows = [(s, e) for s, e, n in host if n == "bench.window"]
     if len(windows) != 1:
         raise ValueError(f"expected one bench.window span, found {len(windows)}")
     studies = sorted((s, e) for s, e, n in host if n == "bench.study")
-    return Trace(windows[0], studies, devices, host)
+    return Trace(windows[0], studies, devices, host, args)
 
 
 def load(path) -> Trace:

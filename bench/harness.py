@@ -2,9 +2,11 @@
 
 Everything a cell needs is found by name: ``BENCHMARK.json`` names the
 cell's configuration and traffic mix, ``bench/configs/<config>.json``
-holds the study template, ``bench/traffic/<mix>.json`` what one window
-submits, ``bench/metrics/<metric>.py`` the reader of each per-layer
-metric. No name of a cell, configuration, mix or metric appears in code.
+holds the study template, ``bench/lowering/<config>.py`` the reference
+GEMM stream of a ``schedule`` configuration (``bench/reference.py``),
+``bench/traffic/<mix>.json`` what one window submits,
+``bench/metrics/<metric>.py`` the reader of each per-layer metric. No
+name of a cell, configuration, mix or metric appears in code.
 
 A traffic mix may hold:
 

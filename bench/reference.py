@@ -7,7 +7,10 @@ test. Given the study spec a run submitted, it returns the payload the
 study must produce, in the JSON form of ``StudyResult.to_dict()``:
 
 - ``schedule`` (one network stream over a budget x tier grid, dOS,
-  steady thermal model): pass-1 (R, C) search per layer and design
+  steady thermal model): the configuration's GEMM stream from its own
+  lowering, ``bench/lowering/<config name>.py``, whose ``lower(model,
+  shape)`` returns the unique ``(M, K, N)`` and their counts; then the
+  pass-1 (R, C) search per layer and design
   point, the candidate fixed designs, their re-evaluation with the
   area / power / lumped-thermal models, the budget-matched 2D baseline,
   and the per-layer and fixed policy totals;
@@ -27,7 +30,9 @@ equations.
 from __future__ import annotations
 
 import functools
+import importlib.util
 import math
+import pathlib
 
 import numpy as np
 
@@ -54,48 +59,11 @@ G_EDGE_PER_MM_W_K = 0.02
 
 EXACT = ("int64", "float64")
 
+LOWERINGS = pathlib.Path(__file__).resolve().parent / "lowering"
+
 
 def _cdiv(a, b):
     return -(-a // b)
-
-
-# ---------------------------------------------------------------------------
-# Workloads
-# ---------------------------------------------------------------------------
-
-def lower_moe(model: dict, shape: dict):
-    """GEMM stream of one execution of a MoE decoder: ``[(M, K, N), ...]``
-    unique shapes in first-seen order and their multiplicities.
-
-    Weight GEMMs only: q/k/v/o projections, router, routed experts at
-    the expected per-expert token count ceil(t * top_k / n_experts),
-    shared experts, logits. Prefill streams one sequence per pass
-    (M = seq_len, counts times the batch); decode is one batched step
-    (M = batch). Gated (silu) FFNs run two input projections.
-    """
-    if shape["mode"] == "decode":
-        t, mult = shape["global_batch"], 1
-    else:
-        t, mult = shape["seq_len"], shape["global_batch"]
-    L, d = model["n_layers"], model["d_model"]
-    q_out = model["n_heads"] * model["head_dim"]
-    kv_out = model["n_kv_heads"] * model["head_dim"]
-    E, ff = model["n_experts"], model["expert_d_ff"]
-    n_in = 2 if model["act"] == "silu" else 1
-    routed = max(1, _cdiv(t * model["top_k"], E))
-    items = [
-        (t, d, q_out, L), (t, d, kv_out, 2 * L), (t, q_out, d, L),
-        (t, d, E, L),
-        (routed, d, ff, n_in * E * L), (routed, ff, d, E * L),
-        (t, d, ff, n_in * model["n_shared_experts"] * L),
-        (t, ff, d, model["n_shared_experts"] * L),
-        (t, d, model["vocab"], 1),
-    ]
-    merged: dict[tuple[int, int, int], int] = {}
-    for M, K, N, n in items:
-        if n > 0:
-            merged[(M, K, N)] = merged.get((M, K, N), 0) + n * mult
-    return list(merged), list(merged.values())
 
 
 # ---------------------------------------------------------------------------
@@ -362,13 +330,29 @@ def fig7(gemms, space: dict, dtype=EXACT, tie: str = "first"):
     }
 
 
+@functools.lru_cache(maxsize=None)
+def lowering(name: str):
+    """``lower(model, shape)`` of the configuration ``name``, from
+    ``bench/lowering/<name>.py``. A configuration without that file has
+    no reference stream: the look-up fails, it never falls back to
+    another network's lowering."""
+    path = LOWERINGS / f"{name}.py"
+    if not path.is_file():
+        raise LookupError(f"configuration {name!r} has no reference lowering: "
+                          f"a schedule study needs bench/lowering/{name}.py")
+    spec = importlib.util.spec_from_file_location(f"bench_lowering_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.lower
+
+
 def payload(config: dict, traffic: dict, spec: dict, dtype=EXACT,
             tie: str = "first") -> dict:
     """The payload the submitted study ``spec`` must produce."""
     analysis, space = spec["analysis"], spec["space"]
     if analysis["kind"] == "schedule":
         shape = traffic["shape"]
-        gemms, counts = lower_moe(config["model"], shape)
+        gemms, counts = lowering(config["name"])(config["model"], shape)
         names = {"arch": spec["workload"]["arch"], "shape": shape["name"],
                  "mode": shape["mode"]}
         limit = spec.get("constraints", {}).get("thermal_limit_c", 105.0)

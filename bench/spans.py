@@ -5,8 +5,10 @@
 device->host fetch of each launch, candidate selection, pricing,
 thermal). They land on the benchmark's thread line beside
 ``bench.study``, so ``devtrace.from_profile`` already holds them in
-``Trace.host``. A program without them gives the readers nothing to
-read: the span readers here return ``None`` then, never a zero.
+``Trace.host``, and their keyword arguments (``rows``, ``width``,
+``launches``, ``candidates``, ``points``) in ``Trace.args``. A program
+without them gives the readers nothing to read: the span readers here
+return ``None`` then, never a zero.
 """
 
 from __future__ import annotations
@@ -34,6 +36,15 @@ def intervals(trace, name: str) -> list:
             if e > s:
                 out.append((s, e))
     return sorted(out)
+
+
+def arg_total(trace, name: str, key: str) -> float | None:
+    """Sum of the argument ``key`` over the spans named ``name`` that
+    lie in the window. ``None`` where no such span carries it."""
+    lo, hi = trace.window
+    values = [a[key] for (s, e, n), a in zip(trace.host, trace.args)
+              if n == name and key in a and min(e, hi) > max(s, lo)]
+    return sum(values) if values else None
 
 
 def total(cover: list) -> float:

@@ -6,7 +6,7 @@ every number is counted by hand below.
 Times in units of 10 us (the proto holds picoseconds):
 
     host    bench.window [0, 21]; bench.study [1, 6] and [9, 18];
-            np.argmin [15, 18]
+            repro.search [2, 5] (rows 81, width 128); np.argmin [15, 18]
     TPU:0   ops fusion.1 [1, 3], copy.2 [2, 4], fusion.1 [11, 13],
             fusion.1 [13.4, 14]; modules jit_run [1, 4], [11, 14]
     TPU:1   ops fusion.1 [1, 11]
@@ -29,9 +29,14 @@ import harness  # noqa: E402
 UNIT = 10_000_000  # picoseconds in 10 us
 
 
-def _ev(meta, start, end):
+STATS = {"rows": 1, "width": 2}
+
+
+def _ev(meta, start, end, **stats):
+    st = "".join(f"stats {{ metadata_id: {STATS[k]} int64_value: {v} }} "
+                 for k, v in stats.items())
     return (f"events {{ metadata_id: {meta} offset_ps: {round(start * UNIT)} "
-            f"duration_ps: {round((end - start) * UNIT)} }}")
+            f"duration_ps: {round((end - start) * UNIT)} {st}}}")
 
 
 def _plane(pid, name, lines, names):
@@ -43,13 +48,18 @@ def _plane(pid, name, lines, names):
         f'event_metadata {{ key: {k} value {{ id: {k} name: "{n}" }} }} '
         for k, n in names.items()
     )
-    return f'planes {{ id: {pid} name: "{name}" {body} {meta} }}'
+    stats = "".join(
+        f'stat_metadata {{ key: {k} value {{ id: {k} name: "{n}" }} }} '
+        for n, k in STATS.items()
+    )
+    return f'planes {{ id: {pid} name: "{name}" {body} {meta} {stats} }}'
 
 
 TEXT = " ".join([
     _plane(1, "/host:CPU", [("python", [
-        _ev(1, 0, 21), _ev(2, 1, 6), _ev(2, 9, 18), _ev(3, 15, 18)])],
-        {1: "bench.window", 2: "bench.study", 3: "np.argmin"}),
+        _ev(1, 0, 21), _ev(2, 1, 6), _ev(4, 2, 5, rows=81, width=128), _ev(2, 9, 18),
+        _ev(3, 15, 18)])],
+        {1: "bench.window", 2: "bench.study", 3: "np.argmin", 4: "repro.search"}),
     _plane(2, "/device:TPU:0", [
         ("XLA Ops", [_ev(1, 1, 3), _ev(2, 2, 4), _ev(1, 11, 13), _ev(1, 13.4, 14)]),
         ("XLA Modules", [_ev(3, 1, 4), _ev(3, 11, 14)])],
@@ -73,6 +83,13 @@ def test_structure(trace):
     assert len(trace.devices[0].ops) == 4 and len(trace.devices[0].modules) == 2
 
 
+def test_host_events_keep_their_arguments(trace):
+    assert len(trace.args) == len(trace.host)
+    got = {n: a for (_, _, n), a in zip(trace.host, trace.args)}
+    assert got["repro.search"] == {"rows": 81, "width": 128}
+    assert got["bench.window"] == {} and got["np.argmin"] == {}
+
+
 def test_busy_is_the_union_of_intervals(trace):
     lo, hi = trace.window
     assert devtrace.union(trace.devices[0].ops, lo, hi) == [
@@ -92,6 +109,8 @@ def test_metric_readers(trace):
     assert harness.load_reader("search.device_ms")(trace) == pytest.approx(0.05)
     # 2 program executions on the first device, 2 studies
     assert harness.load_reader("search.launches")(trace) == 1.0
+    # 81 rows searched over 2 studies
+    assert harness.load_reader("search.points")(trace) == 40.5
 
 
 def test_readers_find_nothing_without_a_device(trace):

@@ -111,7 +111,7 @@ def test_broken_search_is_not_correct(name, fault, capsys, monkeypatch):
     assert result["checks"]["mismatches"]["value"] > 0
 
 
-@pytest.mark.parametrize("name", ONE_CHIP)
+@pytest.mark.parametrize("name", ONE_CHIP + FOUR_CHIPS)
 def test_control_is_not_correct(name):
     cell = harness.load_cell(name)
     limits = cell["config_data"]["limits"]

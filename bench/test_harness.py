@@ -96,6 +96,44 @@ def test_comparison_catches_one_changed_cycle(name):
     assert compare.compare(bad, want)["mismatches"] == 1
 
 
+#: the reference's deepseek-moe-16b stream of each shape, pinned:
+#: (M, K, N, count)
+MOE_STREAMS = {
+    "prefill_32k": [(32768, 2048, 2048, 3584), (32768, 2048, 64, 896),
+                    (3072, 2048, 1408, 114688), (3072, 1408, 2048, 57344),
+                    (32768, 2048, 1408, 3584), (32768, 1408, 2048, 1792),
+                    (32768, 2048, 102400, 32)],
+    "decode_32k": [(128, 2048, 2048, 112), (128, 2048, 64, 28),
+                   (12, 2048, 1408, 3584), (12, 1408, 2048, 1792),
+                   (128, 2048, 1408, 112), (128, 1408, 2048, 56),
+                   (128, 2048, 102400, 1)],
+}
+
+
+@pytest.mark.parametrize("shape", sorted(MOE_STREAMS))
+def test_moe_lowering_is_pinned(shape):
+    cell = harness.load_cell(f"deepseek-moe-16b.{shape}")
+    config = cell["config_data"]
+    gemms, counts = reference.lowering(config["name"])(
+        config["model"], cell["traffic_data"]["shape"])
+    assert [(*g, n) for g, n in zip(gemms, counts)] == MOE_STREAMS[shape]
+
+
+def test_schedule_without_a_lowering_fails():
+    cell = harness.load_cell("deepseek-moe-16b.decode_32k")
+    config = dict(cell["config_data"], name="no-such-network")
+    spec = harness.Traffic(config, cell["traffic_data"], SEED).study(0)
+    with pytest.raises(LookupError, match="bench/lowering/no-such-network.py"):
+        reference.payload(config, cell["traffic_data"], spec)
+
+
+@pytest.mark.parametrize("path", sorted((BENCH / "lowering").glob("*.py")),
+                         ids=lambda p: p.stem)
+def test_lowerings_import_nothing_of_the_program(path):
+    imports = re.findall(r"^\s*(?:from|import)\s+([\w.]+)", path.read_text(), re.M)
+    assert not [m for m in imports if m.split(".")[0] == "repro"], imports
+
+
 def test_comparison_rules():
     assert compare.compare({"a": 1.5}, {"a": 1.5})["float_gap"] == 0.0
     assert compare.compare({"a": 1.5 + 1e-12}, {"a": 1.5})["float_gap"] > 0

@@ -17,6 +17,11 @@ Times in milliseconds (the trace holds nanoseconds):
         repro.search [64, 105]
             repro.search.fetch [70, 80], [95, 105]
     repro.lower [120, 125], after the window
+    repro.search [130, 140], after the window
+
+Arguments: the three repro.search spans carry rows 336, 903 and 5, and
+widths; the first two fetches rows 256 and 80; the first repro.evaluate
+points 1260 and the first repro.select candidates 21.
     TPU:0 ops [12, 17], [26, 27], [71, 75], [90, 96]; TPU:1 busy [0, 100]
 
 Clipped to the window, the second study ends at 100, its search at 100
@@ -44,8 +49,11 @@ HOST = [
     (35, 38, "repro.thermal"), (41, 45, "repro.select"), (42, 44, "np.argmin"),
     (63, 110, "repro.study"), (64, 105, "repro.search"),
     (70, 80, "repro.search.fetch"), (95, 105, "repro.search.fetch"),
-    (120, 125, "repro.lower"),
+    (120, 125, "repro.lower"), (130, 140, "repro.search"),
 ]
+ARGS = {5: {"points": 1260}, 6: {"rows": 336, "width": 32768}, 7: {"rows": 256},
+        8: {"rows": 80}, 11: {"candidates": 21}, 14: {"rows": 903, "width": 32768},
+        18: {"rows": 5, "width": 128}}
 OPS = [(12, 17), (26, 27), (71, 75), (90, 96)]
 
 
@@ -63,6 +71,7 @@ def trace():
             devtrace.Device("/device:TPU:1", _ns([(0, 100, "fusion.1")]), []),
         ],
         host=_ns(HOST),
+        args=[ARGS.get(i, {}) for i in range(len(HOST))],
     )
 
 
@@ -77,6 +86,16 @@ def test_intervals_are_clipped_to_the_window(trace):
     # the lowering after the window is dropped
     assert spans.intervals(trace, "repro.lower") == _ns([(7, 9)])
     assert spans.intervals(trace, "repro.nothing") == []
+
+
+def test_argument_totals_keep_to_the_window(trace):
+    # the third search lies after the window; the fetches' rows are theirs
+    assert spans.arg_total(trace, "repro.search", "rows") == 336 + 903
+    assert spans.arg_total(trace, "repro.search.fetch", "rows") == 256 + 80
+    assert spans.arg_total(trace, "repro.evaluate", "points") == 1260
+    assert spans.arg_total(trace, "repro.select", "candidates") == 21
+    assert spans.arg_total(trace, "repro.search", "launches") is None
+    assert spans.arg_total(trace, "repro.nothing", "rows") is None
 
 
 def test_overlap_of_two_covers():
@@ -123,11 +142,12 @@ def test_metric_readers(trace):
     assert _read("pricing.host_ms", trace) == pytest.approx(2.0)
     assert _read("thermal.host_ms", trace) == pytest.approx(1.5)
     assert _read("host.unspanned_ms", trace) == pytest.approx(12.5)
+    assert _read("search.points", trace) == (336 + 903) / 2
 
 
 READERS = ("lowering.host_ms", "dispatch.host_ms", "dispatch.fetch_idle_ms",
            "select.host_ms", "pricing.host_ms", "thermal.host_ms",
-           "host.unspanned_ms")
+           "host.unspanned_ms", "search.points")
 
 
 @pytest.mark.parametrize("name", READERS)
