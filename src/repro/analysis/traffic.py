@@ -57,7 +57,9 @@ def attn_ssm_layer_split(cfg: ArchConfig) -> tuple[int, int]:
 
 def kv_bytes_per_context_token(cfg: ArchConfig, bytes_kv: int = _B2) -> float:
     """kv-cache footprint [bytes] of ONE context token across all
-    attention layers (K + V, ``n_kv_heads x head_dim`` each).
+    attention layers (K + V, ``n_kv_heads x head_dim`` each; latent
+    attention caches one latent plus the shared rope key,
+    ``kv_lora_rank + qk_rope_head_dim`` values, per layer).
 
     A decode step reads ``context_len *`` this per request (the full
     cache shard read of ``traffic_bytes_per_device``) and writes one
@@ -65,7 +67,11 @@ def kv_bytes_per_context_token(cfg: ArchConfig, bytes_kv: int = _B2) -> float:
     against the DRAM interface.
     """
     n_attn, _ = attn_ssm_layer_split(cfg)
-    return float(n_attn * 2 * cfg.n_kv_heads * cfg.head_dim_ * bytes_kv)
+    if cfg.kv_lora_rank:
+        per_layer = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    else:
+        per_layer = 2 * cfg.n_kv_heads * cfg.head_dim_
+    return float(n_attn * per_layer * bytes_kv)
 
 
 def state_bytes_per_request(cfg: ArchConfig) -> float:

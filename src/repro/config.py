@@ -41,6 +41,17 @@ class ArchConfig:
     n_shared_experts: int = 0
     top_k: int = 0
     expert_d_ff: int = 0
+    n_dense_layers: int = 0  # leading dense FFN layers (width d_ff)
+    router_score: str = "softmax"  # softmax | sigmoid (deepseek-v3)
+    n_expert_groups: int = 0  # group-limited routing: experts in groups,
+    topk_groups: int = 0  # tokens routed within the best topk_groups
+    routed_scale: float = 1.0  # routed-expert output scale
+    # --- latent attention (MLA; active when kv_lora_rank > 0) -----------
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM / hybrid -----------------------------------------------------
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -74,12 +85,24 @@ class ArchConfig:
         return self.family in ("ssm", "hybrid") or self.sliding_window > 0
 
     @property
+    def _attn_params(self) -> int:
+        """Weights of one attention layer: q/k/v/o, or MLA's q down/up,
+        kv down (latent + rope key), kv up and o projections."""
+        d, h = self.d_model, self.n_heads
+        if self.kv_lora_rank:
+            dn, dr, dv = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
+            return (d * self.q_lora_rank + self.q_lora_rank * h * (dn + dr)
+                    + d * (self.kv_lora_rank + dr)
+                    + self.kv_lora_rank * h * (dn + dv) + h * dv * d)
+        hd = self.head_dim_
+        return d * hd * (h + 2 * self.n_kv_heads) + h * hd * d
+
+    @property
     def n_params(self) -> int:
         """Approximate parameter count (embeddings included)."""
         d, v = self.d_model, self.vocab
-        hd = self.head_dim_
         emb = v * d * (1 if self.tie_embeddings else 2)
-        att = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        att = self._attn_params
         if self.family == "moe":
             ff_r = 3 * d * self.expert_d_ff * self.n_experts
             ff_s = 3 * d * self.expert_d_ff * self.n_shared_experts
@@ -101,6 +124,8 @@ class ArchConfig:
             total = self.n_layers * (4 * d * d + 2 * d)
         else:
             total = self.n_layers * per_layer
+        if self.family == "moe":  # leading dense layers replace MoE FFNs
+            total += self.n_dense_layers * (3 * d * self.d_ff - ff)
         if self.family == "encdec":
             total += self.n_enc_layers * (att + 3 * d * self.d_ff)
         return total + emb
@@ -112,10 +137,10 @@ class ArchConfig:
             return self.n_params
         d = self.d_model
         ff_active = 3 * d * self.expert_d_ff * (self.top_k + self.n_shared_experts)
-        hd = self.head_dim_
-        att = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        n_moe = self.n_layers - self.n_dense_layers
+        ff = n_moe * ff_active + self.n_dense_layers * 3 * d * self.d_ff
         emb = self.vocab * d * (1 if self.tie_embeddings else 2)
-        return self.n_layers * (att + ff_active) + emb
+        return self.n_layers * self._attn_params + ff + emb
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,6 +194,13 @@ def reduced(cfg: ArchConfig, seq: int = 128) -> ArchConfig:
     if cfg.family == "moe":
         kw.update(n_experts=8, n_shared_experts=min(cfg.n_shared_experts, 1),
                   top_k=min(cfg.top_k, 2), expert_d_ff=64)
+    if cfg.n_dense_layers:  # keep the pattern: dense layer(s), then MoE
+        kw["n_dense_layers"] = 1
+    if cfg.n_expert_groups:  # 4 groups of 2 experts, the best 2 kept
+        kw.update(n_expert_groups=4, topk_groups=2)
+    if cfg.kv_lora_rank:  # latent (32) narrower than the heads' k+v (256)
+        kw.update(q_lora_rank=64, kv_lora_rank=32, qk_nope_head_dim=32,
+                  qk_rope_head_dim=16, v_head_dim=32)
     if cfg.family in ("ssm", "hybrid"):
         kw.update(ssm_state=16, ssm_head_dim=16)
     if cfg.attn_every:

@@ -1,4 +1,4 @@
-"""Architecture config registry: the 10 assigned archs + paper workloads.
+"""Architecture config registry: the 11 zoo archs + paper workloads.
 
 ``get_config(name)`` returns the full ArchConfig; ``reduced(cfg)``
 (from repro.config) gives the smoke-test sizing.
@@ -8,6 +8,7 @@ from __future__ import annotations
 
 from ..config import ArchConfig, ShapeConfig, SHAPES, reduced  # noqa: F401
 from .deepseek_moe_16b import CONFIG as deepseek_moe_16b
+from .deepseek_v3 import CONFIG as deepseek_v3
 from .gemma3_1b import CONFIG as gemma3_1b
 from .llama32_vision_11b import CONFIG as llama32_vision_11b
 from .llama4_scout_17b import CONFIG as llama4_scout_17b
@@ -29,6 +30,7 @@ REGISTRY: dict[str, ArchConfig] = {
         whisper_medium,
         zamba2_27b,
         deepseek_moe_16b,
+        deepseek_v3,
         llama4_scout_17b,
         xlstm_125m,
     ]

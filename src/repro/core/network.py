@@ -17,7 +17,9 @@ helpers):
   with ``count`` multiplied by the batch), and one batched decode step
   (M = global_batch) for ``decode`` shapes.
 - Only *matrix-multiply* work is lowered — exactly what Eqs. 1/2
-  model: attention q/k/v/o projections, MLP up/gate/down, MoE routers
+  model: attention q/k/v/o projections (latent attention's down/up
+  projections, naive in train/prefill and absorbed in decode; see
+  ``_mla``), MLP up/gate/down, MoE routers
   + routed/shared experts (with expected routed token counts), SSM
   in/out projections and the depthwise conv as an im2col GEMM, and
   the logits/unembedding GEMM. Embedding lookups (gathers), softmax,
@@ -186,6 +188,41 @@ def _attention(cfg: ArchConfig, t: int, n_layers: int, prefix: str = ""):
     ]
 
 
+def _mla(cfg: ArchConfig, t: int, n_layers: int, mode: Mode):
+    """Multi-head latent attention (DeepSeek-V2/V3) for ``n_layers``.
+
+    Both paths compute q from a low-rank latent (q_a down, q_b up to
+    every head's nope + rope width) and project the input once to the
+    kv latent plus the shared rope key (kv_a); the cache holds those
+    ``kv_lora_rank + qk_rope_head_dim`` values per token.
+
+    - train/prefill (naive): kv_b expands every token's latent to each
+      head's nope key and value, then the o projection.
+    - decode (absorbed, DeepSeek-V3 ``inference/model.py``
+      ``attn_impl="absorb"``): kv_b is split per head into W_UK and
+      W_UV; the query's nope part is multiplied into the latent space
+      (attn.uk, nope -> latent) and the attention output back out of it
+      (attn.uv, latent -> v), both at M = t once per head, so no token
+      of the context is expanded.
+    """
+    d, h = cfg.d_model, cfg.n_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    kl = cfg.kv_lora_rank
+    out = [
+        LayerGemm("attn.q_a", t, d, cfg.q_lora_rank, n_layers),
+        LayerGemm("attn.q_b", t, cfg.q_lora_rank, h * (dn + dr), n_layers),
+        LayerGemm("attn.kv_a", t, d, kl + dr, n_layers),
+    ]
+    if mode == "decode":
+        out += [
+            LayerGemm("attn.uk", t, dn, kl, h * n_layers),
+            LayerGemm("attn.uv", t, kl, dv, h * n_layers),
+        ]
+    else:
+        out.append(LayerGemm("attn.kv_b", t, kl, h * (dn + dv), n_layers))
+    return out + [LayerGemm("attn.o", t, h * dv, d, n_layers)]
+
+
 def _mlp(cfg: ArchConfig, t: int, n_layers: int, d_ff: int | None = None,
          prefix: str = ""):
     """MLP GEMMs: gated (silu -> gate+up+down) or classic (up+down)."""
@@ -212,31 +249,37 @@ def _lower_dense(cfg: ArchConfig, t: int):
     )
 
 
-def _lower_moe(cfg: ArchConfig, t: int):
-    """MoE: attention as dense; FFN = router + routed + shared experts.
+def _lower_moe(cfg: ArchConfig, t: int, mode: Mode):
+    """MoE: attention as dense, or latent (``_mla``) where the config
+    has a kv latent; the first ``n_dense_layers`` FFNs are dense MLPs
+    of width ``d_ff``, the rest router + routed + shared experts.
 
     Routed expert GEMMs use the *expected* per-expert token count under
     uniform top-k routing, ceil(t * top_k / n_experts) — the quantity
     the paper's M dim sees per expert array pass.
     """
     d = cfg.d_model
+    n_moe = cfg.n_layers - cfg.n_dense_layers
     routed_t = max(1, -(-t * cfg.top_k // cfg.n_experts))
     ff = cfg.expert_d_ff
-    out = _attention(cfg, t, cfg.n_layers)
-    out.append(LayerGemm("moe.router", t, d, cfg.n_experts, cfg.n_layers))
+    if cfg.kv_lora_rank:
+        out = _mla(cfg, t, cfg.n_layers, mode)
+    else:
+        out = _attention(cfg, t, cfg.n_layers)
+    out += _mlp(cfg, t, cfg.n_dense_layers)
+    out.append(LayerGemm("moe.router", t, d, cfg.n_experts, n_moe))
     n_in = 2 if cfg.act == "silu" else 1
     out += [
         LayerGemm("moe.expert.in", routed_t, d, ff,
-                  n_in * cfg.n_experts * cfg.n_layers),
-        LayerGemm("moe.expert.out", routed_t, ff, d,
-                  cfg.n_experts * cfg.n_layers),
+                  n_in * cfg.n_experts * n_moe),
+        LayerGemm("moe.expert.out", routed_t, ff, d, cfg.n_experts * n_moe),
     ]
     if cfg.n_shared_experts:
         out += [
             LayerGemm("moe.shared.in", t, d, ff,
-                      n_in * cfg.n_shared_experts * cfg.n_layers),
+                      n_in * cfg.n_shared_experts * n_moe),
             LayerGemm("moe.shared.out", t, ff, d,
-                      cfg.n_shared_experts * cfg.n_layers),
+                      cfg.n_shared_experts * n_moe),
         ]
     return out + _logits(cfg, t)
 
@@ -337,7 +380,7 @@ def _lower_vlm(cfg: ArchConfig, t: int, mode: Mode):
 
 _LOWERERS = {
     "dense": lambda cfg, t, mode: _lower_dense(cfg, t),
-    "moe": lambda cfg, t, mode: _lower_moe(cfg, t),
+    "moe": _lower_moe,
     "ssm": lambda cfg, t, mode: _lower_ssm(cfg, t),
     "hybrid": lambda cfg, t, mode: _lower_hybrid(cfg, t),
     "encdec": _lower_encdec,

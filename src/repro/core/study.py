@@ -753,8 +753,9 @@ class Study:
         with TraceAnnotation("repro.study", kind=self.analysis.kind):
             if cache is not None and not isinstance(cache, ResultCache):
                 cache = ResultCache(cache)
-            with TraceAnnotation("repro.lower"):
+            with TraceAnnotation("repro.lower") as span:
                 stream = self.workload.resolve()
+                span.set_metadata(gemms=len(stream.workloads))
             runner = getattr(self, f"_run_{self.analysis.kind}")
             if cache is None:
                 payload = runner(stream)

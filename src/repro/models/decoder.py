@@ -1,7 +1,8 @@
 """Unified decoder LM covering the assigned architecture families.
 
 One parameterized decoder serves: dense (llama/smollm/qwen), local:global
-patterns (gemma3), MoE FFNs (deepseek-moe, llama4-scout), vision
+patterns (gemma3), MoE FFNs (deepseek-moe, llama4-scout), latent
+attention over leading dense layers then MoE (deepseek-v3), vision
 cross-attention interleave (llama-3.2-vision), Mamba2+shared-attention
 hybrid (zamba2) and xLSTM stacks (mLSTM/sLSTM).
 
@@ -27,6 +28,7 @@ from .layers import (
     attention, attn_defs, compute_cross_kv, embed_defs, embed_tokens,
     mlp, mlp_defs, rmsnorm, rmsnorm_def, unembed,
 )
+from .mla import mla_attention, mla_defs, mla_init_cache
 from .moe import moe_block, moe_defs
 from .params import ParamDef, stack_defs
 from .ssm import mamba_block, mamba_defs, mamba_init_state
@@ -45,12 +47,13 @@ _GLOBAL_WINDOW = 2**30  # "window" larger than any sequence = global attn
 # --------------------------------------------------------------------------
 
 
-def _block_defs(cfg: ArchConfig):
+def _block_defs(cfg: ArchConfig, moe: bool | None = None):
+    moe = cfg.family == "moe" if moe is None else moe
     d = {
         "ln1": rmsnorm_def(cfg.d_model),
-        "attn": attn_defs(cfg),
+        "attn": mla_defs(cfg) if cfg.kv_lora_rank else attn_defs(cfg),
         "ln2": rmsnorm_def(cfg.d_model),
-        "ffn": moe_defs(cfg) if cfg.family == "moe" else mlp_defs(cfg),
+        "ffn": moe_defs(cfg) if moe else mlp_defs(cfg),
     }
     return d
 
@@ -62,7 +65,11 @@ def decoder_defs(cfg: ArchConfig):
     }
     fam = cfg.family
     if fam in ("dense", "moe"):
-        defs["layers"] = stack_defs(_block_defs(cfg), cfg.n_layers)
+        # leading dense layers (deepseek-v3: 3) stack apart from the rest
+        n_dense = cfg.n_dense_layers
+        if n_dense:
+            defs["dense_layers"] = stack_defs(_block_defs(cfg, moe=False), n_dense)
+        defs["layers"] = stack_defs(_block_defs(cfg), cfg.n_layers - n_dense)
     elif fam == "vlm":
         period = cfg.cross_every  # every Nth layer is a cross layer
         n_groups = cfg.n_layers // period
@@ -126,6 +133,8 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     kvh, hd = cfg.n_kv_heads, cfg.head_dim_
 
     def kv(b=batch, s=max_len):
+        if cfg.kv_lora_rank:
+            return mla_init_cache(cfg, b, s, dtype)
         return {
             "k": jnp.zeros((b, s, kvh, hd), dtype),
             "v": jnp.zeros((b, s, kvh, hd), dtype),
@@ -134,12 +143,14 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
 
     fam = cfg.family
     if fam in ("dense", "moe"):
-        return {
-            "layers": jax.tree.map(
-                lambda x: jnp.broadcast_to(x, (cfg.n_layers,) + x.shape),
-                kv(),
-            )
-        }
+        def stacked(n):
+            return jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + x.shape), kv())
+
+        n_dense = cfg.n_dense_layers
+        out = {"layers": stacked(cfg.n_layers - n_dense)}
+        if n_dense:
+            out["dense_layers"] = stacked(n_dense)
+        return out
     if fam == "vlm":
         period = cfg.cross_every
         n_groups = cfg.n_layers // period
@@ -180,16 +191,22 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
 
 
 def _attn_mlp_block(lp, x, cfg, *, mode, cache, window, theta, cross_kv=None):
-    h, new_cache = attention(
-        lp["attn"],
-        rmsnorm(x, lp["ln1"], cfg.norm_eps),
-        cfg,
-        mode=mode,
-        cache=cache,
-        window=window,
-        theta=theta,
-        cross_kv=cross_kv,
-    )
+    if "wkv_a" in lp["attn"]:  # latent attention (no window, no cross)
+        h, new_cache = mla_attention(
+            lp["attn"], rmsnorm(x, lp["ln1"], cfg.norm_eps), cfg,
+            mode=mode, cache=cache, theta=theta,
+        )
+    else:
+        h, new_cache = attention(
+            lp["attn"],
+            rmsnorm(x, lp["ln1"], cfg.norm_eps),
+            cfg,
+            mode=mode,
+            cache=cache,
+            window=window,
+            theta=theta,
+            cross_kv=cross_kv,
+        )
     if "gate" in lp:  # gated cross-attn (llama-3.2-vision)
         h = jnp.tanh(lp["gate"].astype(jnp.float32)).astype(h.dtype) * h
     x = x + h
@@ -246,16 +263,22 @@ def decoder_forward(
     new_cache = None
 
     if fam in ("dense", "moe"):
-        metas = layer_metadata(cfg)
-        caches = cache["layers"] if cache is not None else None
-        if caches is None and mode != "train":
-            caches = None
-        x, ncache = _scan_blocks(
-            params["layers"], x, cfg, mode=mode, caches=caches, metas=metas,
-            remat=(remat if mode == "train" else False),
-        )
-        if mode != "train":
-            new_cache = {"layers": ncache}
+        wins, thetas = layer_metadata(cfg)
+        nd = cfg.n_dense_layers
+        new_cache = {}
+        # leading dense stack (if any), then the rest
+        for key, sl in (("dense_layers", slice(None, nd)), ("layers", slice(nd, None))):
+            if key not in params:
+                continue
+            x, ncache = _scan_blocks(
+                params[key], x, cfg, mode=mode,
+                caches=cache[key] if cache is not None else None,
+                metas=(wins[sl], thetas[sl]),
+                remat=(remat if mode == "train" else False),
+            )
+            new_cache[key] = ncache
+        if mode == "train":
+            new_cache = None
 
     elif fam == "vlm":
         period = cfg.cross_every
@@ -371,9 +394,19 @@ def decoder_forward(
 
 
 def _pad_cache_tree(cache, max_len):
-    """Pad every kv buffer (dim -3 = seq) up to max_len."""
+    """Pad every kv buffer (dim -3 = seq; a latent cache's dim -2) up
+    to max_len."""
 
     def rec(node):
+        if isinstance(node, dict) and "c" in node and "length" in node:
+            s = node["c"].shape[-2]
+            if s >= max_len:
+                return node
+            padw = [(0, 0)] * node["c"].ndim
+            padw[-2] = (0, max_len - s)
+            return {"c": jnp.pad(node["c"], padw),
+                    "k_pe": jnp.pad(node["k_pe"], padw),
+                    "length": node["length"]}
         if isinstance(node, dict) and "k" in node and "length" in node:
             s = node["k"].shape[-3]
             if s >= max_len:

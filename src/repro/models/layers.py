@@ -92,7 +92,8 @@ def embed_tokens(p, tokens, compute_dtype):
 
 def unembed(p, x, tie: bool):
     w = p["tok"].T if tie else p["head"]
-    logits = proj(x.astype(jnp.bfloat16) if x.dtype == jnp.bfloat16 else x, w)
+    with jax.named_scope("logits"):
+        logits = proj(x.astype(jnp.bfloat16) if x.dtype == jnp.bfloat16 else x, w)
     return shard(logits.astype(jnp.float32), "logits")
 
 
@@ -227,11 +228,13 @@ def mlp_defs(cfg, d_ff=None, act=None):
 
 
 def mlp(p, x, act: str = "silu"):
-    if "wi_gate" in p:
-        g = proj(x, p["wi_gate"])
-        u = proj(x, p["wi_up"])
+    if "wi_gate" in p:  # scopes name the GEMMs as core.network does
+        with jax.named_scope("mlp.in"):
+            g = proj(x, p["wi_gate"])
+            u = proj(x, p["wi_up"])
         hidden = shard(jax.nn.silu(g) * u, "mlp_hidden")
-        y = proj(hidden, p["wo"])
+        with jax.named_scope("mlp.out"):
+            y = proj(hidden, p["wo"])
     else:
         hidden = shard(jax.nn.gelu(proj(x, p["wi"], p["bi"])), "mlp_hidden")
         y = proj(hidden, p["wo"], p["bo"])

@@ -14,13 +14,14 @@ EXPECTED_B = {  # nameplate sizes (rough bands)
     "whisper-medium": (0.6, 1.0),          # our enc-dec variant
     "zamba2-2.7b": (2.2, 3.1),
     "deepseek-moe-16b": (14, 19),
+    "deepseek-v3": (660, 685),             # 671B main model, MTP left out
     "llama4-scout-17b-a16e": (95, 115),    # 17B active / ~109B total
     "xlstm-125m": (0.05, 0.2),   # lean mLSTM blocks, d_ff=0 per assignment
 }
 
 
 def test_all_ten_archs_registered():
-    assert len(REGISTRY) == 10
+    assert len(REGISTRY) == 11
 
 
 @pytest.mark.parametrize("name", sorted(REGISTRY))
@@ -33,8 +34,8 @@ def test_param_counts(name):
 
 def test_cells_cover_assignment():
     live, skipped = cells()
-    assert len(live) + len(skipped) == 40
+    assert len(live) + len(skipped) == 44
     # long_500k runs only for sub-quadratic archs
     longs = [a for a, s in live if s == "long_500k"]
     assert set(longs) == {"gemma3-1b", "zamba2-2.7b", "xlstm-125m"}
-    assert len(skipped) == 7
+    assert len(skipped) == 8

@@ -142,6 +142,14 @@ def test_schedule_opens_every_span(schedule_run):
     assert len(_of(spans, "repro.select")) == 2  # candidates, policies
 
 
+@pytest.mark.parametrize("run", ["schedule_run", "fig7_run"])
+def test_lower_span_carries_the_rows_lowered(run, request):
+    _, _, spans, _ = request.getfixturevalue(run)
+    (lower,) = _of(spans, "repro.lower")
+    spec = SCHEDULE if run == "schedule_run" else FIG7
+    assert lower[3] == {"gemms": len(Study.from_dict(spec).workload.resolve().workloads)}
+
+
 def test_schedule_spans_nest_as_the_layers(schedule_run):
     _, _, spans, _ = schedule_run
     for fetch in _of(spans, "repro.search.fetch"):
