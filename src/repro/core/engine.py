@@ -481,13 +481,25 @@ class EvalResult:
 
 @functools.lru_cache(maxsize=32)
 def _jax_search_fn(r_max_total: int):
+    """jit of the (R, C) search at one static width. It takes (rows,)
+    int64 ``(D1, D2, Tser, budget)`` and returns r, c and tau stacked
+    inside the same program as one (3, rows) int64 array, so each
+    launch's results come back to the host in one copy."""
     import jax
     import jax.numpy as jnp
 
     def search_rc(D1, D2, Tser, budget):
-        return _search_rc(jnp, D1, D2, Tser, budget, r_max_total)
+        return jnp.stack(_search_rc(jnp, D1, D2, Tser, budget, r_max_total))
 
     return jax.jit(search_rc)
+
+
+def _fetch(out, rows: int) -> np.ndarray:
+    """One launch's (3, rows) results on the host: a stacked device
+    array is one copy; a tuple of r, c, tau is read as it stands."""
+    with TraceAnnotation("repro.search.fetch", rows=rows,
+                         copies=len(out) if isinstance(out, tuple) else 1):
+        return np.asarray(out)
 
 
 def _search_batch(D1, D2, Tser, budget, backend: str, chunk: int | None,
@@ -496,7 +508,10 @@ def _search_batch(D1, D2, Tser, budget, backend: str, chunk: int | None,
 
     ``chunk`` is rows per device launch; None derives it from the width
     the batch searches, ~2^23 candidates a launch (numpy: 64..2048
-    rows). Results never depend on it.
+    rows). Results never depend on it. On the jax backend each launch
+    returns one stacked (3, rows) array, fetched to the host in one
+    device->host copy (span ``repro.search.fetch``, ``copies`` 1) and
+    unpacked into r, c and tau.
 
     ``n_shards`` > 1 (jax backend) splits each launch across the local
     JAX devices via ``parallel.shard_eval``, ``chunk`` rows per device —
@@ -536,11 +551,8 @@ def _search_batch(D1, D2, Tser, budget, backend: str, chunk: int | None,
                 fn = _jax_search_fn(r_max)
                 for lo in range(0, B, rows):
                     hi = min(lo + rows, B)
-                    r, c, t = fn(D1[lo:hi], D2[lo:hi], Tser[lo:hi], budget[lo:hi])
-                    with TraceAnnotation("repro.search.fetch", rows=hi - lo):
-                        r_out[lo:hi], c_out[lo:hi], t_out[lo:hi] = (
-                            np.asarray(r), np.asarray(c), np.asarray(t),
-                        )
+                    out = fn(D1[lo:hi], D2[lo:hi], Tser[lo:hi], budget[lo:hi])
+                    r_out[lo:hi], c_out[lo:hi], t_out[lo:hi] = _fetch(out, hi - lo)
             return r_out, c_out, t_out
         if backend != "numpy":
             raise ValueError(f"unknown backend {backend!r}")

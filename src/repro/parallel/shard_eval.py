@@ -60,6 +60,8 @@ def _sharded_search_fn(n_shards: int, r_max_total: int):
 
     Cached per (shard count, static search width) like the engine's
     single-device ``_jax_search_fn`` — one compile per width class.
+    Like it, returns r, c and tau stacked as one (3, rows) int64 array,
+    sharded along the rows.
     """
     import jax
     import jax.numpy as jnp
@@ -71,13 +73,13 @@ def _sharded_search_fn(n_shards: int, r_max_total: int):
     mesh = make_mesh((n_shards,), ("shard",))
 
     def sharded_search_rc(D1, D2, Tser, budget):
-        return _search_rc(jnp, D1, D2, Tser, budget, r_max_total)
+        return jnp.stack(_search_rc(jnp, D1, D2, Tser, budget, r_max_total))
 
     fn = jax.shard_map(
         sharded_search_rc,
         mesh=mesh,
         in_specs=(P("shard"),) * 4,
-        out_specs=(P("shard"),) * 3,
+        out_specs=P(None, "shard"),
     )
     return jax.jit(fn)
 
@@ -89,7 +91,10 @@ def sharded_search(D1, D2, Tser, budget, r_max_total: int, n_shards: int):
     count — the batch is padded with trivial rows (all-ones searches)
     and sliced back, so degenerate batches (B < n_shards, B == 1) are
     exact. Caller is expected to hold jax's ``enable_x64`` scope, like
-    the engine's unsharded jax path.
+    the engine's unsharded jax path. The chips' stacked (3, rows)
+    results come back in one device->host copy (span
+    ``repro.search.fetch``, ``copies`` 1); returns (r, c, tau), three
+    (B,) int64 numpy arrays.
     """
     B = D1.shape[0]
     pad = (-B) % n_shards
@@ -99,10 +104,7 @@ def sharded_search(D1, D2, Tser, budget, r_max_total: int, n_shards: int):
             np.concatenate([a, one]) for a in (D1, D2, Tser, budget)
         )
     fn = _sharded_search_fn(n_shards, r_max_total)
-    r, c, t = fn(D1, D2, Tser, budget)
-    with TraceAnnotation("repro.search.fetch", rows=B):
-        return (
-            np.asarray(r)[:B],
-            np.asarray(c)[:B],
-            np.asarray(t)[:B],
-        )
+    out = fn(D1, D2, Tser, budget)
+    with TraceAnnotation("repro.search.fetch", rows=B, copies=1):
+        r, c, t = np.asarray(out)[:, :B]
+    return r, c, t

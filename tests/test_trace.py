@@ -208,6 +208,13 @@ def test_search_spans_carry_the_batch_searched(run, request):
     assert len(_of(spans, "repro.search.fetch")) == _launches(calls)
 
 
+@pytest.mark.parametrize("run", RUNS)
+def test_fetch_spans_carry_one_copy(run, request):
+    _, _, spans, _ = request.getfixturevalue(run)
+    fetches = _of(spans, "repro.search.fetch")
+    assert fetches and all(f[3] == {"rows": f[3]["rows"], "copies": 1} for f in fetches)
+
+
 def test_schedule_select_counts_the_candidates(schedule_run):
     _, traced, spans, _ = schedule_run
     first = _of(spans, "repro.select")[0]
