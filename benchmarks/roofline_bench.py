@@ -13,9 +13,7 @@ independent references:
     contract that keeps every pre-bandwidth result valid.
 
 Prints the points/s throughput and bound histogram, and writes
-``BENCH_roofline.json`` next to this file. The TPU dry-run artifact
-table this benchmark used to print now lives in
-``experiments/make_report.py`` (``python -m repro report``).
+``BENCH_roofline.json`` next to this file.
 
 Run:  PYTHONPATH=src python -m benchmarks.roofline_bench [--n 300] [--smoke]
 """

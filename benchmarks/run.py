@@ -5,8 +5,7 @@ Prints ``name,us_per_call,derived`` CSV. Sections:
   - tab2/fig8/fig9: PPA model reproductions
   - kernels/*: op microbenchmarks (CPU wall time)
   - roofline/*: the engine-backed bandwidth/roofline sweep
-    (benchmarks.roofline_bench; the dry-run artifact table moved to
-    ``python -m repro report``)
+    (benchmarks.roofline_bench)
 """
 
 from __future__ import annotations

@@ -1,14 +1,10 @@
-"""Quickstart: the paper's model, the simulator, and a tiny training run.
+"""Quickstart: the paper's model, the simulator, the advisor and one
+bandwidth-aware study.
 
-Run:  PYTHONPATH=src python examples/quickstart.py [--smoke]
-(--smoke trims the training section to a few steps — the CI fast path.)
+Run:  PYTHONPATH=src python examples/quickstart.py
 """
 
-import sys
-
 import numpy as np
-
-SMOKE = "--smoke" in sys.argv
 
 # --- 1. The paper's analytical model (Eqs. 1-2) -----------------------------
 from repro.core.analytical import optimal_tiers, speedup_3d, tau_2d, tau_3d
@@ -51,11 +47,3 @@ print(f"bandwidth-aware: best feasible tier count {int(r.grid.tiers[best])}, "
       f"{r.speedup[0, best]:.2f}x vs 2D ({r.bound[0, best]}-bound — the "
       f"compute-bound {speedup_3d(M, K, N, 2**18, l):.2f}x needs infinite DRAM)")
 
-# --- 5. Train a tiny model end to end ------------------------------------------
-from repro.configs import REGISTRY, reduced
-from repro.launch.train import train_loop
-
-cfg = reduced(REGISTRY["smollm-135m"])
-steps = 5 if SMOKE else 20
-_, losses, _ = train_loop(cfg, steps=steps, global_batch=4, seq_len=64, log_every=5)
-print(f"tiny LM loss: {losses[0]:.3f} -> {losses[-1]:.3f}")

@@ -1,88 +1,28 @@
-"""Generate the §Dry-run, §Roofline, §DSE, §Network, §Search and
-§Calibrate sections.
+"""Generate the §Roofline, §DSE, §Network, §Search, §Calibrate, §Serve
+and §Thermal sections.
 
 Usage: PYTHONPATH=src python -m repro report            (the front door)
    or: PYTHONPATH=src python experiments/make_report.py [--sections ...]
-Writes experiments/dryrun_section.md, experiments/roofline_section.md,
-experiments/dse_section.md and experiments/network_section.md. The
-roofline, DSE and network tables are recomputed live through
-declarative ``core.study.Study`` specs — one ``roofline`` study (plus
-its compute-bound ``evaluate`` twin) over every Table-I workload x
-budget x tier under ``BandwidthSpec.paper_default()``, one ``evaluate``
-study for the DSE table (optima restricted to thermally feasible
-points), and one ``schedule`` study per model-zoo cell
-(per-layer-optimal vs fixed-design policies). The TPU dry-run
-artifact tables (experiments/dryrun/) are appended when artifacts
-exist. EXPERIMENTS.md includes their content verbatim.
+Writes one ``experiments/<section>_section.md`` each. Every table is
+recomputed live through declarative ``core.study.Study`` specs — e.g.
+one ``roofline`` study (plus its compute-bound ``evaluate`` twin) over
+every Table-I workload x budget x tier under
+``BandwidthSpec.paper_default()``, one ``evaluate`` study for the DSE
+table (optima restricted to thermally feasible points), and one
+``schedule`` study per model-zoo cell (per-layer-optimal vs
+fixed-design policies). EXPERIMENTS.md includes their content verbatim.
 """
 
 from __future__ import annotations
 
-import json
 import pathlib
 
 import numpy as np
 
 HERE = pathlib.Path(__file__).resolve().parent
-ART = HERE / "dryrun"
-
-ARCH_ORDER = [
-    "llama-3.2-vision-11b", "smollm-135m", "qwen2.5-3b", "qwen2-72b",
-    "gemma3-1b", "whisper-medium", "zamba2-2.7b", "deepseek-moe-16b",
-    "llama4-scout-17b-a16e", "xlstm-125m",
-]
-SHAPE_ORDER = ["train_4k", "prefill_32k", "decode_32k", "long_500k"]
 
 
-def load(strategy=None):
-    arts = {}
-    for p in sorted(ART.glob("*.json")):
-        a = json.loads(p.read_text())
-        if strategy and a.get("strategy") != strategy:
-            continue
-        arts[(a["arch"], a["shape"], a["mesh"], a.get("strategy", "dos"))] = a
-    return arts
-
-
-def fmt_bytes(b):
-    return f"{b/2**30:.2f} GiB"
-
-
-def dryrun_section(arts):
-    lines = [
-        "### Per-cell dry-run results (strategy: dos = paper-faithful baseline)",
-        "",
-        "| arch | shape | mesh | compile | GB/dev | HLO GFLOPs/dev | collectives (counts) |",
-        "|---|---|---|---|---|---|---|",
-    ]
-    ok_single = ok_multi = fail = 0
-    for arch in ARCH_ORDER:
-        for shape in SHAPE_ORDER:
-            for mesh in ("pod16x16", "pod2x16x16"):
-                a = arts.get((arch, shape, mesh, "dos"))
-                if a is None:
-                    continue
-                if "error" in a:
-                    fail += 1
-                    lines.append(f"| {arch} | {shape} | {mesh} | **FAIL** | | | {a['error'][:60]} |")
-                    continue
-                if mesh == "pod16x16":
-                    ok_single += 1
-                else:
-                    ok_multi += 1
-                cost = a.get("cost_corrected", a["cost"])
-                cc = a.get("collectives_corrected", a["collectives"])["counts"]
-                cstr = " ".join(f"{k.split('-')[-1][:4]}:{v}" for k, v in sorted(cc.items()))
-                lines.append(
-                    f"| {arch} | {shape} | {mesh} | {a['compile_s']:.0f}s "
-                    f"| {a['memory']['peak_per_device_gb']:.1f} "
-                    f"| {cost.get('flops',0)/1e9:,.0f} | {cstr} |"
-                )
-    lines.insert(0, f"**{ok_single} single-pod + {ok_multi} multi-pod cells compiled OK; {fail} failures.**\n")
-    return "\n".join(lines) + "\n"
-
-
-def roofline_section(arts, mac_budgets=(2**14, 2**16, 2**18), max_tiers=16,
+def roofline_section(mac_budgets=(2**14, 2**16, 2**18), max_tiers=16,
                      cache=None):
     """Engine-backed roofline: the paper's Table-I workloads under a
     finite memory system (``BandwidthSpec.paper_default()``), next to
@@ -93,8 +33,7 @@ def roofline_section(arts, mac_budgets=(2**14, 2**16, 2**18), max_tiers=16,
     ``roofline`` (DRAM + SRAM reuse + TSV vertical links) — so the
     table shows, per (workload, budget): the compute-optimal tier
     count and speedup, the bandwidth-aware winner (which can differ),
-    its bound class, and the stall share. The TPU dry-run artifact
-    table (when artifacts exist) follows as the scale-out counterpart.
+    its bound class, and the stall share.
     """
     from repro.core.bandwidth import BandwidthSpec
     from repro.core.dse import PAPER_WORKLOADS
@@ -154,44 +93,7 @@ def roofline_section(arts, mac_budgets=(2**14, 2**16, 2**18), max_tiers=16,
         f"bandwidth-aware tier optimum differs from the compute-bound one "
         f"in {flips}/{W * B} (workload, budget) cells."
     )
-    if arts:
-        lines += ["", "### TPU dry-run roofline (scale-out counterpart)", ""]
-        lines += _artifact_roofline_table(arts)
     return "\n".join(lines) + "\n"
-
-
-def _artifact_roofline_table(arts):
-    lines = [
-        "| arch | shape | GB/dev | compute s | memory s (hlo / kernel) | collective s | dominant | MODEL/HLO | MFU | what would move the dominant term |",
-        "|---|---|---|---|---|---|---|---|---|---|",
-    ]
-    for arch in ARCH_ORDER:
-        for shape in SHAPE_ORDER:
-            a = arts.get((arch, shape, "pod16x16", "dos"))
-            if a is None or "error" in a:
-                continue
-            r = a["roofline"]
-            note = _note(a)
-            lines.append(
-                f"| {arch} | {shape} | {a['memory']['peak_per_device_gb']:.1f} "
-                f"| {r['compute_s']:.3f} | {r['memory_s']:.3f} / {r['memory_s_kernel']:.3f} "
-                f"| {r['collective_s']:.3f} | **{r['dominant']}** "
-                f"| {r['useful_ratio']:.2f} | {r['mfu']*100:.2f}% | {note} |"
-            )
-    return lines
-
-
-def _note(a):
-    r = a["roofline"]
-    d = r["dominant"]
-    if d == "collective":
-        return ("drop pure-dOS K-sharding where M*N/device is large "
-                "(advisor: megatron/DP mix); reduce-scatter chaining")
-    if d == "memory":
-        if a["mode"] == "decode":
-            return "cache layout/quantization; batch more requests per step"
-        return "fuse optimizer+grad traffic; larger microbatches"
-    return "near roofline: block-size/layout tuning only"
 
 
 def dse_section(mac_budgets=(2**14, 2**16, 2**18), max_tiers=16, cache=None):
@@ -481,67 +383,32 @@ def thermal_section(cache=None):
     return "\n".join(lines) + "\n"
 
 
+SECTIONS = {
+    "roofline": roofline_section, "dse": dse_section,
+    "network": network_section, "search": search_section,
+    "calibrate": calibrate_section, "serve": serve_section,
+    "thermal": thermal_section,
+}
+
+
 def main(sections=None, cache=None):
     """Regenerate the requested sections (None = all). This is what
     ``python -m repro report`` drives. ``cache`` (a directory path)
     makes the live DSE/network studies chunk-cached: re-generating the
     report recomputes nothing that already ran — the sections come out
     bit-identical either way (chunking never changes results)."""
-    sections = (
-        set(sections)
-        if sections
-        else {"dryrun", "roofline", "dse", "network", "search", "calibrate",
-              "serve", "thermal"}
-    )
     if cache is not None:
         from repro.core.cache import ResultCache
 
         cache = cache if isinstance(cache, ResultCache) else ResultCache(cache)
-    arts = load() if sections & {"dryrun", "roofline"} else {}
-    if "dryrun" in sections:
-        (HERE / "dryrun_section.md").write_text(dryrun_section(arts))
-    if "roofline" in sections:
-        (HERE / "roofline_section.md").write_text(roofline_section(arts, cache=cache))
-    if "dse" in sections:
-        (HERE / "dse_section.md").write_text(dse_section(cache=cache))
-    if "network" in sections:
-        (HERE / "network_section.md").write_text(network_section(cache=cache))
-    if "search" in sections:
-        (HERE / "search_section.md").write_text(search_section(cache=cache))
-    if "calibrate" in sections:
-        (HERE / "calibrate_section.md").write_text(calibrate_section(cache=cache))
-    if "serve" in sections:
-        (HERE / "serve_section.md").write_text(serve_section(cache=cache))
-    if "thermal" in sections:
-        (HERE / "thermal_section.md").write_text(thermal_section(cache=cache))
-    if "roofline" not in sections:
-        return
-    # machine-readable summary for the hillclimb
-    rows = []
-    for (arch, shape, mesh, strat), a in arts.items():
-        if mesh != "pod16x16" or "error" in a:
-            continue
-        r = a["roofline"]
-        rows.append({
-            "arch": arch, "shape": shape, "strategy": strat,
-            "dominant": r["dominant"], "step_s": r["step_s"],
-            "mfu": r["mfu"], "collective_s": r["collective_s"],
-            "compute_s": r["compute_s"],
-            "mem_gb": a["memory"]["peak_per_device_gb"],
-        })
-    rows.sort(key=lambda x: x["mfu"])
-    (HERE / "summary.json").write_text(json.dumps(rows, indent=1))
-    print(f"{len(rows)} single-pod cells summarized; worst MFU:")
-    for r in rows[:6]:
-        print(f"  {r['arch']}/{r['shape']}/{r['strategy']}: mfu={r['mfu']*100:.2f}% "
-              f"dom={r['dominant']} step={r['step_s']*1e3:.1f}ms mem={r['mem_gb']}GB")
+    for name, build in SECTIONS.items():
+        if not sections or name in sections:
+            (HERE / f"{name}_section.md").write_text(build(cache=cache))
 
 
 if __name__ == "__main__":
     import argparse
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--sections", nargs="*", default=None,
-                    choices=["dryrun", "roofline", "dse", "network", "search",
-                             "calibrate", "serve", "thermal"])
+    ap.add_argument("--sections", nargs="*", default=None, choices=list(SECTIONS))
     main(sections=ap.parse_args().sections)

@@ -3,9 +3,9 @@
 Written for jax 0.9.0. Two things live here:
 
 - ``make_mesh``: jax 0.9's ``jax.make_mesh`` defaults to Explicit axis
-  types, while the repo's sharding rules (``parallel.axes``) rely on
-  Auto axes — sharding constraints the compiler propagates — so every
-  mesh is built here.
+  types; the sharded design-space search (``parallel.shard_eval``) and
+  the tests build their meshes here with Auto axes, whose shardings the
+  compiler propagates.
 - ``use_compile_cache``: where JAX's persistent compilation cache
   lives. Entry points (``python -m repro``, ``chip_smoke.py``, the
   benchmark scripts) call it once at start-up; importing this module

@@ -218,7 +218,7 @@ def build_parser() -> argparse.ArgumentParser:
 
     rep = sub.add_parser("report", help="regenerate the experiments/ sections")
     rep.add_argument("--sections", nargs="*", default=None,
-                     choices=["dryrun", "roofline", "dse", "network", "search",
+                     choices=["roofline", "dse", "network", "search",
                               "calibrate", "serve", "thermal"],
                      help="subset to regenerate (default: all)")
     rep.add_argument("--cache", nargs="?", const="", default=None, metavar="DIR",

@@ -1,8 +1,7 @@
-"""Configuration system: architectures, input shapes, runs.
+"""Configuration system: architectures and input shapes.
 
 Every assigned architecture is an ``ArchConfig`` in ``repro.configs``;
-every benchmark shape is a ``ShapeConfig``. ``RunConfig`` composes them
-with a mesh/parallelism choice for the launcher and dry-run.
+every benchmark shape is a ``ShapeConfig``.
 """
 
 from __future__ import annotations
@@ -10,7 +9,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Literal
 
-__all__ = ["ArchConfig", "ShapeConfig", "RunConfig", "SHAPES", "reduced"]
+__all__ = ["ArchConfig", "ShapeConfig", "SHAPES", "reduced"]
 
 Mode = Literal["train", "prefill", "decode"]
 
@@ -64,13 +63,9 @@ class ArchConfig:
     # --- VLM ---------------------------------------------------------------
     cross_every: int = 0  # every Nth decoder layer is vision cross-attn
     n_image_tokens: int = 0
-    # --- numerics / compilation -------------------------------------------
+    # --- numerics ------------------------------------------------------------
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
-    scan_layers: bool = True
-    # unroll inner chunk-scans (flash/SSD) so cost_analysis counts every
-    # trip — used by the dry-run's small unrolled cost variants only.
-    unroll_inner: bool = False
     # --- provenance ---------------------------------------------------------
     source: str = ""
     notes: str = ""
@@ -159,18 +154,6 @@ SHAPES = {
 }
 
 
-@dataclasses.dataclass(frozen=True)
-class RunConfig:
-    arch: ArchConfig
-    shape: ShapeConfig
-    strategy: str = "dos"  # dos | megatron | auto
-    fsdp: bool = True  # shard params/opt over data axis (train)
-    multi_pod: bool = False
-    pipeline: bool = False  # pipeline-parallel over the pod axis
-    remat: str = "layer"  # none | layer | full
-    microbatches: int = 1
-
-
 def reduced(cfg: ArchConfig, seq: int = 128) -> ArchConfig:
     """A smoke-test-sized config of the same family: small dims, few
     layers, tiny vocab — but the same block structure and patterns."""
@@ -182,7 +165,6 @@ def reduced(cfg: ArchConfig, seq: int = 128) -> ArchConfig:
         head_dim=32,
         d_ff=256 if cfg.d_ff else 0,
         vocab=256,
-        scan_layers=cfg.scan_layers,
         param_dtype="float32",
         compute_dtype="float32",
     )

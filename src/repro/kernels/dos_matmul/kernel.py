@@ -9,9 +9,6 @@ The paper's dOS dataflow adapted to the TPU memory hierarchy:
 - The output tile stays **stationary in a VMEM f32 scratch accumulator**
   across all K-steps (the "output stationary" part); partial sums are
   accumulated in-register/VMEM instead of over TSVs.
-- The cross-*chip* tier dimension (the paper's physical stacking) is
-  provided by ``repro.parallel``: K is additionally sharded over the
-  mesh's model axis and the adder pile becomes an all-reduce.
 
 Block shapes are chosen MXU-aligned (multiples of 128 in M/N, K-block a
 multiple of the dtype's packing); the VMEM working set is

@@ -2,9 +2,9 @@
 
 ``dos_matmul`` is the layer-facing entry point used by the model zoo.
 On TPU it calls the Pallas kernel; on CPU (this container) it uses the
-pure-jnp reference so smoke tests and the multi-pod dry-run lower plain
-XLA HLO. ``interpret=True`` forces the Pallas kernel in interpret mode
-(used by the kernel test-suite to validate the kernel body on CPU).
+pure-jnp reference so smoke tests lower plain XLA HLO.
+``interpret=True`` forces the Pallas kernel in interpret mode (used by
+the kernel test-suite to validate the kernel body on CPU).
 """
 
 from __future__ import annotations
@@ -79,8 +79,8 @@ def dos_matmul(
     up to block multiples and the result is sliced back.
 
     Dispatch: on TPU -> Pallas kernel; on CPU -> jnp reference (so smoke
-    tests and the dry-run lower plain XLA HLO). Pass ``interpret=True``
-    to force the kernel body in interpret mode (kernel test-suite).
+    tests lower plain XLA HLO). Pass ``interpret=True`` to force the
+    kernel body in interpret mode (kernel test-suite).
     """
     out_dtype = out_dtype or a.dtype
     if interpret is None:

@@ -1,10 +1,9 @@
 """Chunked flash attention in pure jnp with a flash-2 custom VJP.
 
-This is the CPU/dry-run twin of the Pallas kernel: the same online-
-softmax chunking (KV blocks = sequential "tiers", output/m/l stationary)
-expressed as a ``lax.scan`` so the lowered HLO has O(S*d) residency —
-the dry-run's memory_analysis and roofline then reflect the kernel's
-true behaviour instead of a naive S x S materialization.
+This is the CPU twin of the Pallas kernel: the same online-softmax
+chunking (KV blocks = sequential "tiers", output/m/l stationary)
+expressed as a ``lax.scan`` so the lowered HLO has O(S*d) residency,
+as the kernel has, instead of a naive S x S materialization.
 
 Forward saves only (q, k, v, o, lse); the backward recomputes p per
 block (flash-2):
@@ -82,8 +81,8 @@ def _fwd_impl(q, k, v, window, causal, scale, q_offset, chunk, unroll=False):
         return (m_new, l, acc, j + 1), None
 
     # init carries derived from q so their varying-axes match inside
-    # shard_map bodies (pipeline parallelism traces this under manual
-    # collectives; constants would be non-varying and scan would reject).
+    # shard_map bodies (constants would be non-varying and scan would
+    # reject them there).
     zq = jnp.zeros_like(qf)
     m0 = zq[..., 0] + NEG_INF
     l0 = zq[..., 0]

@@ -2,11 +2,11 @@
 
 ``flash_attention``: training/prefill attention over full sequences.
 On TPU it dispatches to the Pallas kernel; on CPU to the jnp reference
-(clean HLO for smoke tests and the multi-pod dry-run).
+(clean HLO for smoke tests).
 
 ``decode_attention``: single-token attention against a KV cache. This
 is a bandwidth-bound matvec (no flash tiling needed); implemented as
-einsum so XLA shards it freely across the mesh.
+einsum.
 """
 
 from __future__ import annotations
@@ -16,7 +16,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ...parallel.axes import shard
 from .chunked import flash_core
 from .kernel import flash_attention_pallas
 from .ref import attention_ref
@@ -27,7 +26,7 @@ __all__ = ["flash_attention", "decode_attention", "flash_attention_jnp"]
 def flash_attention_jnp(q, k, v, *, causal=True, window=None, scale=None,
                         q_offset=0, chunk=512, unroll=False):
     """Chunked flash attention (custom-VJP lax.scan) on (B,S,H,D)
-    layouts — the CPU/dry-run path with kernel-equivalent memory
+    layouts — the CPU path with kernel-equivalent memory
     behaviour. ``window`` may be a traced scalar."""
     b, sq, h, d = q.shape
     _, skv, kvh, _ = k.shape
@@ -127,9 +126,6 @@ def decode_attention(
 
     qf = (q.astype(jnp.float32) * scale).astype(q.dtype)
     qf = qf.reshape(b, kvh, g, d)
-    # match the cache layout (KVH-sharded when divisible) so the logits
-    # einsum partitions by head instead of all-gathering the cache.
-    qf = shard(qf, "decode_q_kvh")
     # Transpose the cache to (B, KVH, S, D) — in its storage dtype, so
     # no f32 second copy is materialized (f32 accumulation comes from
     # preferred_element_type). With KVH leading, both contractions lower

@@ -6,7 +6,7 @@ Returns (y: (Bt, S, H, P), final_state: (Bt, H, N, P) f32).
 
 On TPU dispatches to the Pallas chunked kernel (per-(batch, head)
 rows); on CPU uses a *chunked jnp implementation with identical math*
-(so the dry-run HLO reflects the real matmul structure, not a length-S
+(so the lowered HLO has the real matmul structure, not a length-S
 scan). The step-by-step reference remains the validation oracle.
 """
 
@@ -25,7 +25,7 @@ __all__ = ["ssm_scan", "ssm_scan_chunked_jnp"]
 
 def ssm_scan_chunked_jnp(u, ld, B, C, chunk: int = 128, unroll: bool = False):
     """Chunked SSD in plain jnp — the same math as the Pallas kernel,
-    vectorized over (batch, head); used on CPU and for the dry-run."""
+    vectorized over (batch, head); used on CPU."""
     bt, s, h, p = u.shape
     n = B.shape[-1]
     assert s % chunk == 0, (s, chunk)

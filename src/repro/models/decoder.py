@@ -78,7 +78,7 @@ def decoder_defs(cfg: ArchConfig):
         cross = {
             "ln1": rmsnorm_def(cfg.d_model),
             "attn": attn_defs(cfg),
-            "gate": ParamDef((1,), ("one",), init="zeros"),
+            "gate": ParamDef((1,), init="zeros"),
             "ln2": rmsnorm_def(cfg.d_model),
             "ffn": mlp_defs(cfg),
         }
@@ -218,10 +218,8 @@ def _attn_mlp_block(lp, x, cfg, *, mode, cache, window, theta, cross_kv=None):
     return x + y, new_cache
 
 
-def _scan_blocks(stacked_params, x, cfg, *, mode, caches, metas, remat=False):
-    """lax.scan over a homogeneous stack of attn+ffn blocks.
-    ``cfg.scan_layers=False`` fully unrolls (used by the dry-run's cost
-    variants so cost_analysis counts every layer)."""
+def _scan_blocks(stacked_params, x, cfg, *, mode, caches, metas):
+    """lax.scan over a homogeneous stack of attn+ffn blocks."""
     win_arr, theta_arr = metas
 
     def body(carry, xs):
@@ -231,17 +229,7 @@ def _scan_blocks(stacked_params, x, cfg, *, mode, caches, metas, remat=False):
         )
         return y, new_cache
 
-    if remat:
-        policy = (
-            jax.checkpoint_policies.save_only_these_names("gathered_w")
-            if remat == "save_gathered" else None
-        )
-        body = jax.checkpoint(body, policy=policy)
-    x, new_caches = jax.lax.scan(
-        body, x, (stacked_params, win_arr, theta_arr, caches),
-        unroll=not cfg.scan_layers,
-    )
-    return x, new_caches
+    return jax.lax.scan(body, x, (stacked_params, win_arr, theta_arr, caches))
 
 
 def decoder_forward(
@@ -253,7 +241,6 @@ def decoder_forward(
     cache=None,
     image_embeds=None,  # (B, n_img, E) for vlm
     max_len: int = 0,  # decode capacity for prefill-produced caches
-    remat: bool = False,
 ):
     """Returns (logits, new_cache)."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
@@ -274,7 +261,6 @@ def decoder_forward(
                 params[key], x, cfg, mode=mode,
                 caches=cache[key] if cache is not None else None,
                 metas=(wins[sl], thetas[sl]),
-                remat=(remat if mode == "train" else False),
             )
             new_cache[key] = ncache
         if mode == "train":
@@ -300,7 +286,6 @@ def decoder_forward(
             )
             x, nc = _scan_blocks(
                 sp, x, cfg, mode=mode, caches=cg, metas=(win_g[g], theta_g[g]),
-                remat=(remat if mode == "train" else False),
             )
             if mode == "decode":
                 ckv = (cross_cache["k"][g], cross_cache["v"][g])
@@ -349,8 +334,7 @@ def decoder_forward(
                 )
             else:
                 gc_in = gc
-            mb = jax.checkpoint(mbody) if (remat and mode == "train") else mbody
-            x, nst = jax.lax.scan(mb, x, (gp, gc_in), unroll=not cfg.scan_layers)
+            x, nst = jax.lax.scan(mbody, x, (gp, gc_in))
             ac = (
                 jax.tree.map(lambda a: a[g], attn_caches)
                 if attn_caches is not None

@@ -3,18 +3,18 @@
 Pure-functional: every block is ``(params, x, ...) -> y`` with params
 described by ParamDef trees. All GEMMs route through ``proj`` which
 dispatches to the dOS Pallas kernel on TPU and plain jnp elsewhere.
-Activations carry logical sharding constraints (``parallel.axes.shard``).
+Weight GEMMs run under named scopes carrying their names in the lowered
+stream (``attn.q``, ``mlp.in`` ...), so a traced jaxpr can be held
+against ``core.network``.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from ..kernels.dos_matmul import dos_matmul
 from ..kernels.flash_attention import decode_attention, flash_attention
-from ..parallel.axes import shard
 from .params import ParamDef
 
 __all__ = [
@@ -24,13 +24,8 @@ __all__ = [
 
 
 def proj(x, w, b=None):
-    """x (..., K) @ w (K, N) in compute dtype, f32 accumulation.
-
-    The cast weight is checkpoint-named so the `save_gathered` remat
-    policy can keep FSDP/ZeRO all-gather results across the backward
-    pass instead of re-gathering (§Perf A3)."""
-    w_c = checkpoint_name(w.astype(x.dtype), "gathered_w")
-    y = dos_matmul(x, w_c, out_dtype=x.dtype)
+    """x (..., K) @ w (K, N) in compute dtype, f32 accumulation."""
+    y = dos_matmul(x, w.astype(x.dtype), out_dtype=x.dtype)
     if b is not None:
         y = y + b.astype(x.dtype)
     return y
@@ -39,8 +34,8 @@ def proj(x, w, b=None):
 # --- norms ---------------------------------------------------------------
 
 
-def rmsnorm_def(dim: int, axes=("embed",)):
-    return ParamDef((dim,), axes, init="ones" if len(axes) else "ones")
+def rmsnorm_def(dim: int):
+    return ParamDef((dim,), init="ones")
 
 
 def rmsnorm(x, scale, eps: float = 1e-6, plus_one: bool = False):
@@ -77,24 +72,21 @@ def rope(x, positions, theta):
 
 
 def embed_defs(cfg):
-    defs = {"tok": ParamDef((cfg.vocab, cfg.d_model), ("vocab", "embed"), scale=0.02)}
+    defs = {"tok": ParamDef((cfg.vocab, cfg.d_model), scale=0.02)}
     if not cfg.tie_embeddings:
-        defs["head"] = ParamDef(
-            (cfg.d_model, cfg.vocab), ("embed", "vocab"), contract=0, out=1
-        )
+        defs["head"] = ParamDef((cfg.d_model, cfg.vocab), contract=0)
     return defs
 
 
 def embed_tokens(p, tokens, compute_dtype):
-    x = jnp.take(p["tok"], tokens, axis=0).astype(compute_dtype)
-    return shard(x, "residual")
+    return jnp.take(p["tok"], tokens, axis=0).astype(compute_dtype)
 
 
 def unembed(p, x, tie: bool):
     w = p["tok"].T if tie else p["head"]
     with jax.named_scope("logits"):
         logits = proj(x.astype(jnp.bfloat16) if x.dtype == jnp.bfloat16 else x, w)
-    return shard(logits.astype(jnp.float32), "logits")
+    return logits.astype(jnp.float32)
 
 
 # --- attention ---------------------------------------------------------------
@@ -103,18 +95,18 @@ def unembed(p, x, tie: bool):
 def attn_defs(cfg, cross: bool = False):
     e, h, kvh, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     defs = {
-        "wq": ParamDef((e, h * hd), ("embed", "heads_flat"), contract=0, out=1),
-        "wk": ParamDef((e, kvh * hd), ("embed", "heads_flat"), contract=0, out=1),
-        "wv": ParamDef((e, kvh * hd), ("embed", "heads_flat"), contract=0, out=1),
-        "wo": ParamDef((h * hd, e), ("heads_flat", "embed"), contract=0, out=1),
+        "wq": ParamDef((e, h * hd), contract=0),
+        "wk": ParamDef((e, kvh * hd), contract=0),
+        "wv": ParamDef((e, kvh * hd), contract=0),
+        "wo": ParamDef((h * hd, e), contract=0),
     }
     if cfg.qkv_bias:
-        defs["bq"] = ParamDef((h * hd,), ("heads_flat",), init="zeros")
-        defs["bk"] = ParamDef((kvh * hd,), ("heads_flat",), init="zeros")
-        defs["bv"] = ParamDef((kvh * hd,), ("heads_flat",), init="zeros")
+        defs["bq"] = ParamDef((h * hd,), init="zeros")
+        defs["bk"] = ParamDef((kvh * hd,), init="zeros")
+        defs["bv"] = ParamDef((kvh * hd,), init="zeros")
     if cfg.qk_norm:
-        defs["q_norm"] = rmsnorm_def(hd, ("head_dim",))
-        defs["k_norm"] = rmsnorm_def(hd, ("head_dim",))
+        defs["q_norm"] = rmsnorm_def(hd)
+        defs["k_norm"] = rmsnorm_def(hd)
     return defs
 
 
@@ -123,9 +115,10 @@ def compute_cross_kv(p, kv_src, cfg):
     to (k, v) once — cached at prefill, reused every decode step."""
     b, skv, _ = kv_src.shape
     kvh, hd = cfg.n_kv_heads, cfg.head_dim_
-    k = proj(kv_src, p["wk"], p.get("bk")).reshape(b, skv, kvh, hd)
-    v = proj(kv_src, p["wv"], p.get("bv")).reshape(b, skv, kvh, hd)
-    return shard(k, "kv_cache"), shard(v, "kv_cache")
+    with jax.named_scope("cross.kv"):
+        k = proj(kv_src, p["wk"], p.get("bk")).reshape(b, skv, kvh, hd)
+        v = proj(kv_src, p["wv"], p.get("bv")).reshape(b, skv, kvh, hd)
+    return k, v
 
 
 def attention(
@@ -144,11 +137,14 @@ def attention(
     """The universal attention block. Returns (y, new_cache)."""
     b, s, e = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
+    scope = "attn" if cross_kv is None else "cross"
 
-    q = proj(x, p["wq"], p.get("bq")).reshape(b, s, h, hd)
+    with jax.named_scope(f"{scope}.q"):
+        q = proj(x, p["wq"], p.get("bq")).reshape(b, s, h, hd)
     if cross_kv is None:
-        k = proj(x, p["wk"], p.get("bk")).reshape(b, s, kvh, hd)
-        v = proj(x, p["wv"], p.get("bv")).reshape(b, s, kvh, hd)
+        with jax.named_scope("attn.kv"):
+            k = proj(x, p["wk"], p.get("bk")).reshape(b, s, kvh, hd)
+            v = proj(x, p["wv"], p.get("bv")).reshape(b, s, kvh, hd)
     else:
         k, v = cross_kv
 
@@ -161,29 +157,23 @@ def attention(
 
     new_cache = None
     if cross_kv is not None:
-        q = shard(q, "attn_heads")
         skv = k.shape[1]
         if mode == "decode":
             o = decode_attention(q, k, v, length=skv, window=None)
         else:
-            o = flash_attention(
-                q, k, v, causal=False, window=None, unroll=cfg.unroll_inner
-            )
+            o = flash_attention(q, k, v, causal=False, window=None)
     elif mode == "decode":
         assert cache is not None and s == 1
         length = cache["length"]
         if theta is not None:
             q = rope(q, length, theta)
             k = rope(k, length, theta)
-        q = shard(q, "attn_heads")
         kc = jax.lax.dynamic_update_slice(
             cache["k"], k.astype(cache["k"].dtype), (0, length, 0, 0)
         )
         vc = jax.lax.dynamic_update_slice(
             cache["v"], v.astype(cache["v"].dtype), (0, length, 0, 0)
         )
-        kc = shard(kc, "kv_cache")
-        vc = shard(vc, "kv_cache")
         o = decode_attention(q, kc, vc, length=length + 1, window=win)
         new_cache = {"k": kc, "v": vc, "length": length + 1}
     else:
@@ -192,18 +182,13 @@ def attention(
                 positions = jnp.arange(s)
             q = rope(q, positions, theta)
             k = rope(k, positions, theta)
-        q = shard(q, "attn_heads")
-        k = shard(k, "kv_cache")
-        v = shard(v, "kv_cache")
-        o = flash_attention(
-            q, k, v, causal=causal, window=win, unroll=cfg.unroll_inner
-        )
+        o = flash_attention(q, k, v, causal=causal, window=win)
         if mode == "prefill":
             new_cache = {"k": k, "v": v, "length": jnp.int32(s)}
 
-    o = shard(o, "attn_heads")
-    y = proj(o.reshape(b, s, h * hd), p["wo"])
-    return shard(y, "residual"), new_cache
+    with jax.named_scope(f"{scope}.o"):
+        y = proj(o.reshape(b, s, h * hd), p["wo"])
+    return y, new_cache
 
 
 # --- MLP -----------------------------------------------------------------------
@@ -215,15 +200,15 @@ def mlp_defs(cfg, d_ff=None, act=None):
     act = act or cfg.act
     if act == "silu":  # gated (llama family)
         return {
-            "wi_gate": ParamDef((e, f), ("embed", "mlp"), contract=0, out=1),
-            "wi_up": ParamDef((e, f), ("embed", "mlp"), contract=0, out=1),
-            "wo": ParamDef((f, e), ("mlp", "embed"), contract=0, out=1),
+            "wi_gate": ParamDef((e, f), contract=0),
+            "wi_up": ParamDef((e, f), contract=0),
+            "wo": ParamDef((f, e), contract=0),
         }
     return {  # plain 2-layer (whisper)
-        "wi": ParamDef((e, f), ("embed", "mlp"), contract=0, out=1),
-        "bi": ParamDef((f,), ("mlp",), init="zeros"),
-        "wo": ParamDef((f, e), ("mlp", "embed"), contract=0, out=1),
-        "bo": ParamDef((e,), ("embed",), init="zeros"),
+        "wi": ParamDef((e, f), contract=0),
+        "bi": ParamDef((f,), init="zeros"),
+        "wo": ParamDef((f, e), contract=0),
+        "bo": ParamDef((e,), init="zeros"),
     }
 
 
@@ -232,10 +217,7 @@ def mlp(p, x, act: str = "silu"):
         with jax.named_scope("mlp.in"):
             g = proj(x, p["wi_gate"])
             u = proj(x, p["wi_up"])
-        hidden = shard(jax.nn.silu(g) * u, "mlp_hidden")
         with jax.named_scope("mlp.out"):
-            y = proj(hidden, p["wo"])
-    else:
-        hidden = shard(jax.nn.gelu(proj(x, p["wi"], p["bi"])), "mlp_hidden")
-        y = proj(hidden, p["wo"], p["bo"])
-    return shard(y, "residual")
+            return proj(jax.nn.silu(g) * u, p["wo"])
+    hidden = jax.nn.gelu(proj(x, p["wi"], p["bi"]))
+    return proj(hidden, p["wo"], p["bo"])

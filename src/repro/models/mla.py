@@ -47,13 +47,13 @@ def mla_defs(cfg):
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
     return {
-        "wq_a": ParamDef((e, ql), ("embed", "q_lora"), contract=0, out=1),
-        "q_norm": rmsnorm_def(ql, ("q_lora",)),
-        "wq_b": ParamDef((ql, h * (dn + dr)), ("q_lora", "heads_flat"), contract=0, out=1),
-        "wkv_a": ParamDef((e, kl + dr), ("embed", "kv_lora"), contract=0, out=1),
-        "kv_norm": rmsnorm_def(kl, ("kv_lora",)),
-        "wkv_b": ParamDef((kl, h * (dn + dv)), ("kv_lora", "heads_flat"), contract=0, out=1),
-        "wo": ParamDef((h * dv, e), ("heads_flat", "embed"), contract=0, out=1),
+        "wq_a": ParamDef((e, ql), contract=0),
+        "q_norm": rmsnorm_def(ql),
+        "wq_b": ParamDef((ql, h * (dn + dr)), contract=0),
+        "wkv_a": ParamDef((e, kl + dr), contract=0),
+        "kv_norm": rmsnorm_def(kl),
+        "wkv_b": ParamDef((kl, h * (dn + dv)), contract=0),
+        "wo": ParamDef((h * dv, e), contract=0),
     }
 
 

@@ -14,11 +14,6 @@ Each weight GEMM runs under a named scope carrying its name in the
 lowered stream (``moe.router``, ``moe.expert.in`` ...), so a traced
 jaxpr can be held against ``core.network``.
 
-Sharding: expert FFN weights are TP-sharded under both strategies
-(dOS: contraction dim; megatron: expert_ff dim). An expert-parallel
-shard_map path with all_to_all dispatch lives in ``parallel.moe_ep``
-(beyond-paper optimization).
-
 Paper connection: each routed expert GEMM has K = expert_d_ff (tiny for
 fine-grained MoE). The advisor (core.advisor) correctly scores dOS as
 unattractive here — the paper's small-K finding (Fig. 5, green curves).
@@ -29,7 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..parallel.axes import shard
 from .layers import proj
 from .params import ParamDef
 
@@ -41,19 +35,19 @@ def moe_defs(cfg):
     f = cfg.expert_d_ff
     ne = cfg.n_experts
     defs = {
-        "router": ParamDef((e, ne), ("embed", "experts"), contract=0, out=1),
-        "wi_gate": ParamDef((ne, e, f), ("experts", "embed", "expert_ff"), contract=1, out=2),
-        "wi_up": ParamDef((ne, e, f), ("experts", "embed", "expert_ff"), contract=1, out=2),
-        "wo": ParamDef((ne, f, e), ("experts", "expert_ff", "embed"), contract=1, out=2),
+        "router": ParamDef((e, ne), contract=0),
+        "wi_gate": ParamDef((ne, e, f), contract=1),
+        "wi_up": ParamDef((ne, e, f), contract=1),
+        "wo": ParamDef((ne, f, e), contract=1),
     }
     if cfg.router_score == "sigmoid":  # selection bias (aux-loss-free balancing)
-        defs["router_bias"] = ParamDef((ne,), ("experts",), init="zeros")
+        defs["router_bias"] = ParamDef((ne,), init="zeros")
     if cfg.n_shared_experts:
         fs = f * cfg.n_shared_experts
         defs["shared"] = {
-            "wi_gate": ParamDef((e, fs), ("embed", "mlp"), contract=0, out=1),
-            "wi_up": ParamDef((e, fs), ("embed", "mlp"), contract=0, out=1),
-            "wo": ParamDef((fs, e), ("mlp", "embed"), contract=0, out=1),
+            "wi_gate": ParamDef((e, fs), contract=0),
+            "wi_up": ParamDef((e, fs), contract=0),
+            "wo": ParamDef((fs, e), contract=0),
         }
     return defs
 
@@ -105,7 +99,6 @@ def moe_block(p, x, cfg):
         g = jax.lax.ragged_dot(xs, p["wi_gate"].astype(xs.dtype), group_sizes)
         u = jax.lax.ragged_dot(xs, p["wi_up"].astype(xs.dtype), group_sizes)
     h = jax.nn.silu(g) * u  # (T*K, F)
-    h = shard(h, "mlp_hidden")
     with jax.named_scope("moe.expert.out"):
         y_sorted = jax.lax.ragged_dot(h, p["wo"].astype(h.dtype), group_sizes)
 
@@ -123,11 +116,11 @@ def moe_block(p, x, cfg):
         with jax.named_scope("moe.shared.out"):
             y = y + proj(jax.nn.silu(sg) * su, sp["wo"])
 
-    return shard(y.reshape(b, s, e).astype(x.dtype), "residual")
+    return y.reshape(b, s, e).astype(x.dtype)
 
 
 def aux_load_balance_loss(p, x, cfg):
-    """Switch-style load-balance auxiliary loss (used by train_step)."""
+    """Switch-style load-balance auxiliary loss (added by ``Model.loss``)."""
     b, s, e = x.shape
     xt = x.reshape(b * s, e)
     logits = proj(xt.astype(jnp.float32), p["router"].astype(jnp.float32))

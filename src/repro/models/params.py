@@ -1,9 +1,9 @@
 """Parameter definition trees.
 
-A model is described by a pytree of ``ParamDef`` leaves (shape, logical
-axes, GEMM contraction/output axis indices, initializer). From one tree
-we derive: real parameters (``materialize``), ShapeDtypeStructs for the
-dry-run (``abstract``), and NamedShardings (``parallel.axes``).
+A model is described by a pytree of ``ParamDef`` leaves (shape, GEMM
+contraction axis index, initializer). From one tree we derive real
+parameters (``materialize``) and ShapeDtypeStructs for tracing
+(``abstract``).
 """
 
 from __future__ import annotations
@@ -20,14 +20,9 @@ __all__ = ["ParamDef", "materialize", "abstract", "tree_size", "stack_defs"]
 @dataclasses.dataclass(frozen=True)
 class ParamDef:
     shape: tuple
-    axes: tuple  # logical axis names, len == len(shape)
-    contract: int | None = None  # GEMM contraction axis index (sharded under dOS)
-    out: int | None = None  # GEMM output axis index (sharded under megatron-col)
-    init: str = "normal"  # normal | zeros | ones
+    contract: int | None = None  # GEMM contraction axis index (the fan-in)
+    init: str = "normal"  # normal | zeros | ones | neg_linspace
     scale: float | None = None  # None -> 1/sqrt(fan_in)
-
-    def __post_init__(self):
-        assert len(self.shape) == len(self.axes), (self.shape, self.axes)
 
 
 def _is_def(x):
@@ -69,15 +64,13 @@ def tree_size(defs) -> int:
     )
 
 
-def stack_defs(defs, n: int, axis_name: str = "layers"):
+def stack_defs(defs, n: int):
     """Prepend a stacked-layers dimension to every leaf (for lax.scan)."""
 
     def one(d: ParamDef):
         return ParamDef(
             shape=(n,) + d.shape,
-            axes=(axis_name,) + d.axes,
             contract=None if d.contract is None else d.contract + 1,
-            out=None if d.out is None else d.out + 1,
             init=d.init,
             scale=d.scale,
         )

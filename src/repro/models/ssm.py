@@ -19,7 +19,6 @@ import jax.numpy as jnp
 
 from ..kernels.ssm_scan import ssm_scan
 from ..kernels.ssm_scan.ref import ssm_step_ref
-from ..parallel.axes import shard
 from .layers import proj, rmsnorm
 from .params import ParamDef
 
@@ -34,17 +33,17 @@ def mamba_defs(cfg):
     n = cfg.ssm_state
     h = di // cfg.ssm_head_dim
     return {
-        "wx": ParamDef((e, di), ("embed", "ssm_inner"), contract=0, out=1),
-        "wz": ParamDef((e, di), ("embed", "ssm_inner"), contract=0, out=1),
-        "wB": ParamDef((e, n), ("embed", "state"), contract=0, out=1),
-        "wC": ParamDef((e, n), ("embed", "state"), contract=0, out=1),
-        "wdt": ParamDef((e, h), ("embed", "ssm_heads"), contract=0, out=1),
-        "dt_bias": ParamDef((h,), ("ssm_heads",), init="zeros"),
-        "A": ParamDef((h,), ("ssm_heads",), init="neg_linspace"),
-        "D": ParamDef((h,), ("ssm_heads",), init="ones"),
-        "conv_w": ParamDef((_CONV_W, di), ("conv", "ssm_inner"), init="normal", scale=0.5),
-        "norm": ParamDef((di,), ("ssm_inner",), init="ones"),
-        "wo": ParamDef((di, e), ("ssm_inner", "embed"), contract=0, out=1),
+        "wx": ParamDef((e, di), contract=0),
+        "wz": ParamDef((e, di), contract=0),
+        "wB": ParamDef((e, n), contract=0),
+        "wC": ParamDef((e, n), contract=0),
+        "wdt": ParamDef((e, h), contract=0),
+        "dt_bias": ParamDef((h,), init="zeros"),
+        "A": ParamDef((h,), init="neg_linspace"),
+        "D": ParamDef((h,), init="ones"),
+        "conv_w": ParamDef((_CONV_W, di), init="normal", scale=0.5),
+        "norm": ParamDef((di,), init="ones"),
+        "wo": ParamDef((di, e), contract=0),
     }
 
 
@@ -91,7 +90,6 @@ def mamba_block(p, x, cfg, *, mode: str, state=None):
     x_c, new_conv = _causal_conv(x_in, p["conv_w"], conv_state)
     x_c = jax.nn.silu(x_c)
     xh = x_c.reshape(b, s, h, hd)
-    xh = shard(xh, "attn_heads")
 
     A = p["A"].astype(jnp.float32)
     u = (dt[..., None] * xh.astype(jnp.float32)).astype(x.dtype)
@@ -106,12 +104,10 @@ def mamba_block(p, x, cfg, *, mode: str, state=None):
         )
         y = y1[:, None]  # (B, 1, H, hd)
     else:
-        y, new_ssm = ssm_scan(u, ld, Bh, Ch, unroll=cfg.unroll_inner)
-    new_ssm = shard(new_ssm, "ssm_state")
+        y, new_ssm = ssm_scan(u, ld, Bh, Ch)
 
     y = y + p["D"].astype(x.dtype)[None, None, :, None] * xh
     y = y.reshape(b, s, di)
     y = rmsnorm(y, p["norm"], cfg.norm_eps) * jax.nn.silu(z)
     out = proj(y.astype(x.dtype), p["wo"])
-    new_state = {"ssm": new_ssm, "conv": new_conv}
-    return shard(out, "residual"), new_state
+    return out, {"ssm": new_ssm, "conv": new_conv}

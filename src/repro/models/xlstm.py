@@ -23,7 +23,6 @@ import jax.numpy as jnp
 
 from ..kernels.ssm_scan import ssm_scan
 from ..kernels.ssm_scan.ref import ssm_step_ref
-from ..parallel.axes import shard
 from .layers import proj, rmsnorm
 from .params import ParamDef
 
@@ -42,15 +41,15 @@ def mlstm_defs(cfg):
     n = cfg.ssm_state  # key/query dim per head
     p_ = cfg.ssm_head_dim  # value dim per head
     return {
-        "wq": ParamDef((e, h * n), ("embed", "heads_flat"), contract=0, out=1),
-        "wk": ParamDef((e, h * n), ("embed", "heads_flat"), contract=0, out=1),
-        "wv": ParamDef((e, h * p_), ("embed", "heads_flat"), contract=0, out=1),
-        "wi": ParamDef((e, h), ("embed", "ssm_heads"), contract=0, out=1),
-        "wf": ParamDef((e, h), ("embed", "ssm_heads"), contract=0, out=1),
-        "bf": ParamDef((h,), ("ssm_heads",), init="ones"),
-        "wo_gate": ParamDef((e, h * p_), ("embed", "heads_flat"), contract=0, out=1),
-        "norm": ParamDef((h * p_,), ("heads_flat",), init="ones"),
-        "wo": ParamDef((h * p_, e), ("heads_flat", "embed"), contract=0, out=1),
+        "wq": ParamDef((e, h * n), contract=0),
+        "wk": ParamDef((e, h * n), contract=0),
+        "wv": ParamDef((e, h * p_), contract=0),
+        "wi": ParamDef((e, h), contract=0),
+        "wf": ParamDef((e, h), contract=0),
+        "bf": ParamDef((h,), init="ones"),
+        "wo_gate": ParamDef((e, h * p_), contract=0),
+        "norm": ParamDef((h * p_,), init="ones"),
+        "wo": ParamDef((h * p_, e), contract=0),
     }
 
 
@@ -69,7 +68,6 @@ def mlstm_block(p, x, cfg, *, mode: str, state=None):
     q = proj(x, p["wq"]).reshape(b, s, h, n)
     k = proj(x, p["wk"]).reshape(b, s, h, n) / (n**0.5)
     v = proj(x, p["wv"]).reshape(b, s, h, p_)
-    q = shard(q, "attn_heads")
     i_pre = proj(x, p["wi"]).astype(jnp.float32)  # (B,S,H)
     f_pre = proj(x, p["wf"]).astype(jnp.float32) + p["bf"].astype(jnp.float32)
 
@@ -87,8 +85,8 @@ def mlstm_block(p, x, cfg, *, mode: str, state=None):
         yc, yn = yc[:, None], yn[:, None]
         new_state = {"C": newC, "n": newn}
     else:
-        yc, newC = ssm_scan(v, ld, Bk, q, unroll=cfg.unroll_inner)  # (B,S,H,P)
-        yn, newn = ssm_scan(ones, ld, Bk, q, unroll=cfg.unroll_inner)  # (B,S,H,1)
+        yc, newC = ssm_scan(v, ld, Bk, q)  # (B,S,H,P)
+        yn, newn = ssm_scan(ones, ld, Bk, q)  # (B,S,H,1)
         new_state = {"C": newC, "n": newn}
 
     denom = jnp.maximum(jnp.abs(yn.astype(jnp.float32)), 1.0)
@@ -97,7 +95,7 @@ def mlstm_block(p, x, cfg, *, mode: str, state=None):
     y = rmsnorm(y.astype(x.dtype), p["norm"], cfg.norm_eps)
     o_gate = jax.nn.sigmoid(proj(x, p["wo_gate"]).astype(jnp.float32))
     y = (y.astype(jnp.float32) * o_gate).astype(x.dtype)
-    return shard(proj(y, p["wo"]), "residual"), new_state
+    return proj(y, p["wo"]), new_state
 
 
 # --- sLSTM --------------------------------------------------------------------
@@ -109,14 +107,14 @@ def slstm_defs(cfg):
     d_h = e // h
     # recurrent weights are per-head block-diagonal (xLSTM's heads)
     return {
-        "wz": ParamDef((e, e), ("embed", "heads_flat"), contract=0, out=1),
-        "wi": ParamDef((e, h), ("embed", "ssm_heads"), contract=0, out=1),
-        "wf": ParamDef((e, h), ("embed", "ssm_heads"), contract=0, out=1),
-        "wo_gate": ParamDef((e, e), ("embed", "heads_flat"), contract=0, out=1),
-        "bf": ParamDef((h,), ("ssm_heads",), init="ones"),
-        "r": ParamDef((h, d_h, d_h), ("heads", "head_dim", "head_dim"), scale=0.1),
-        "norm": ParamDef((e,), ("embed",), init="ones"),
-        "wo": ParamDef((e, e), ("heads_flat", "embed"), contract=0, out=1),
+        "wz": ParamDef((e, e), contract=0),
+        "wi": ParamDef((e, h), contract=0),
+        "wf": ParamDef((e, h), contract=0),
+        "wo_gate": ParamDef((e, e), contract=0),
+        "bf": ParamDef((h,), init="ones"),
+        "r": ParamDef((h, d_h, d_h), scale=0.1),
+        "norm": ParamDef((e,), init="ones"),
+        "wo": ParamDef((e, e), contract=0),
     }
 
 
@@ -175,5 +173,4 @@ def slstm_block(p, x, cfg, *, mode: str, state=None):
     )
     y = ys.transpose(1, 0, 2).astype(x.dtype)  # (B,S,E)
     y = rmsnorm(y, p["norm"], cfg.norm_eps)
-    out = proj(y, p["wo"])
-    return shard(out, "residual"), {"c": c, "n": nrm, "h": h_last}
+    return proj(y, p["wo"]), {"c": c, "n": nrm, "h": h_last}

@@ -1,24 +1,25 @@
 """Model zoo facade: one `Model` object per architecture config.
 
-Gives the launcher, dry-run, tests and examples a uniform surface:
+The plain, unsharded reference the lowering (``core.network``) is held
+against: traced by ``jax.make_jaxpr``, its weight GEMMs carry the
+stream's names. One surface for every family:
 
     model = build(cfg)
     params = model.init(rng)
     loss   = model.loss(params, batch)            # train shapes
     logits, cache = model.prefill(params, batch)  # prefill shapes
-    logits, cache = model.decode(params, cache, batch)  # serve_step
-    specs  = model.input_specs(shape)             # ShapeDtypeStructs
+    logits, cache = model.decode(params, cache, batch)  # one decode step
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from ..config import ArchConfig, ShapeConfig
+from ..config import ArchConfig
 from .decoder import decoder_defs, decoder_forward, init_cache
 from .encdec import encdec_defs, encdec_forward, encdec_init_cache
 from .moe import aux_load_balance_loss
@@ -54,25 +55,22 @@ class Model:
 
     # ---- forward ------------------------------------------------------------
     def _forward(self, params, tokens, *, mode, cache=None, batch=None,
-                 max_len=0, remat=False):
+                 max_len=0):
         cfg = self.cfg
         batch = batch or {}
         if cfg.family == "encdec":
             return encdec_forward(
                 params, tokens, cfg, mode=mode,
                 enc_frames=batch.get("enc_frames"), cache=cache,
-                max_len=max_len, remat=remat,
+                max_len=max_len,
             )
         return decoder_forward(
             params, tokens, cfg, mode=mode, cache=cache,
             image_embeds=batch.get("image_embeds"), max_len=max_len,
-            remat=remat,
         )
 
-    def loss(self, params, batch, *, remat: bool = False):
-        logits, _ = self._forward(
-            params, batch["tokens"], mode="train", batch=batch, remat=remat
-        )
+    def loss(self, params, batch):
+        logits, _ = self._forward(params, batch["tokens"], mode="train", batch=batch)
         loss = softmax_xent(logits, batch["labels"])
         if self.cfg.family == "moe":
             # load-balance aux on the first layer's router (cheap proxy)
@@ -103,31 +101,6 @@ class Model:
         return jax.eval_shape(
             lambda: self.init_cache(batch, max_len, dtype)
         )
-
-    # ---- dry-run input specs ---------------------------------------------------
-    def input_specs(self, shape: ShapeConfig) -> dict:
-        """ShapeDtypeStruct stand-ins for every model input (no alloc)."""
-        cfg = self.cfg
-        b, s = shape.global_batch, shape.seq_len
-        i32 = jnp.int32
-        if shape.mode == "train":
-            specs = {
-                "tokens": jax.ShapeDtypeStruct((b, s), i32),
-                "labels": jax.ShapeDtypeStruct((b, s), i32),
-            }
-        elif shape.mode == "prefill":
-            specs = {"tokens": jax.ShapeDtypeStruct((b, s), i32)}
-        else:  # decode: one new token against a seq_len cache
-            specs = {"token": jax.ShapeDtypeStruct((b, 1), i32)}
-        if cfg.family == "vlm" and shape.mode != "decode":
-            specs["image_embeds"] = jax.ShapeDtypeStruct(
-                (b, cfg.n_image_tokens, cfg.d_model), jnp.dtype(cfg.compute_dtype)
-            )
-        if cfg.family == "encdec" and shape.mode != "decode":
-            specs["enc_frames"] = jax.ShapeDtypeStruct(
-                (b, cfg.enc_seq, cfg.d_model), jnp.dtype(cfg.compute_dtype)
-            )
-        return specs
 
 
 def build(cfg: ArchConfig) -> Model:

@@ -1,6 +1,2 @@
-"""Distribution: logical-axis sharding, pipeline, MoE-EP, compression,
-and device-sharded design-space search dispatch (``shard_eval``)."""
-
-from .axes import ShardingRules, current_rules, param_sharding, shard, use_rules
-
-__all__ = ["ShardingRules", "current_rules", "param_sharding", "shard", "use_rules"]
+"""Device-sharded design-space search dispatch (``shard_eval``) and the
+multi-process work queue of the guided search (``work_queue``)."""

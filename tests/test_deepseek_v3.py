@@ -4,12 +4,8 @@ against the program's, and the deepseek-moe-16b stream left as it was.
 
 - (a) ``lower_network(reduced(deepseek-v3))`` in train, prefill and
   decode equals the weight matmuls of the ``models/`` forward, traced
-  by ``jax.make_jaxpr``: every dot under a scope named as a stream
-  GEMM, by (M, K, N) and count, and total MACs. Routed experts run as
-  one ``ragged_dot`` over all routed rows, so they compare by (K, N)
-  and MACs: with t * top_k divisible by the experts, the lowering's
-  ceil(t * top_k / E) rows per expert add up to the rows routed,
-  whatever the seeded routing.
+  by ``jax.make_jaxpr``, as ``test_reference.py`` holds the other
+  decoders; the path follows the mode.
 - (b) prefill then absorbed decode through the latent cache gives the
   naive full forward's logits within ``DECODE_TOL``; in bfloat16 it
   does not.
@@ -21,26 +17,21 @@ against the program's, and the deepseek-moe-16b stream left as it was.
 import dataclasses
 import importlib.util
 import json
-import math
 import pathlib
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.extend import core as jcore
+from test_reference import EXPERTS, assert_stream_equals_the_traced_forward
 
 from repro.analysis.traffic import kv_bytes_per_context_token
-from repro.config import ShapeConfig
 from repro.configs import SHAPES, get_config, reduced
 from repro.core.network import lower_network
 from repro.models import build
-from repro.models.decoder import decoder_forward
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 V3 = get_config("deepseek-v3")
 SMALL = reduced(V3)
-EXPERTS = ("moe.expert.in", "moe.expert.out")
 #: every name the lowering gives a GEMM of this model (the stream keeps
 #: one name per shape, so a merged stream shows fewer)
 GEMM_NAMES = {"attn.q_a", "attn.q_b", "attn.kv_a", "attn.kv_b", "attn.uk",
@@ -62,86 +53,12 @@ DECODE_TOL = 1e-4
 # (a) the stream against the traced forward
 # ---------------------------------------------------------------------------
 
-def _sub_jaxprs(params):
-    for v in params.values():
-        for x in v if isinstance(v, (tuple, list)) else (v,):
-            if isinstance(x, jcore.ClosedJaxpr):
-                yield x.jaxpr
-            elif isinstance(x, jcore.Jaxpr):
-                yield x
-
-
-def _traced_gemms(jaxpr, names, mult=1, scope=""):
-    """(name, M, K, N, count) of every dot under a scope named in
-    ``names``, scan trips multiplied in. The activation is the left
-    operand (the model's weight GEMMs are all ``x @ w``)."""
-    out = []
-    for eqn in jaxpr.eqns:
-        stack = "/".join(x for x in (scope, str(eqn.source_info.name_stack)) if x)
-        prim = eqn.primitive.name
-        if prim in ("dot_general", "ragged_dot_general"):
-            found = [part for part in stack.split("/") if part in names]
-            if not found:
-                continue  # activation x activation (scores, values)
-            lhs, rhs = (v.aval.shape for v in eqn.invars[:2])
-            if prim == "ragged_dot_general":  # (m, k) x (groups, k, n)
-                M, K, N, batch = lhs[0], lhs[1], rhs[2], 1
-            else:
-                (lc, rc), (lb, rb) = eqn.params["dimension_numbers"]
-                M = math.prod(d for i, d in enumerate(lhs) if i not in (*lc, *lb))
-                K = math.prod(lhs[i] for i in lc)
-                N = math.prod(d for i, d in enumerate(rhs) if i not in (*rc, *rb))
-                batch = math.prod(lhs[i] for i in lb)
-            out.append((found[-1], M, K, N, mult * batch))
-            continue
-        trips = eqn.params["length"] if prim == "scan" else 1
-        for sub in _sub_jaxprs(eqn.params):
-            out += _traced_gemms(sub, names, mult * trips, stack)
-    return out
-
-
-def _by_shape(rows):
-    """{(M, K, N): count} and {(M, K, N): names} of non-expert rows;
-    {name: (K, N, MACs)} of the routed experts."""
-    counts, names, experts = {}, {}, {}
-    for name, M, K, N, n in rows:
-        if name in EXPERTS:
-            k, n_, macs = experts.get(name, (K, N, 0))
-            assert (k, n_) == (K, N), name
-            experts[name] = (K, N, macs + M * K * N * n)
-        else:
-            counts[(M, K, N)] = counts.get((M, K, N), 0) + n
-            names.setdefault((M, K, N), set()).add(name)
-    return counts, names, experts
-
-
-def _trace(mode, batch, seq):
-    model = build(SMALL)
-    params = model.abstract_params()
-    if mode == "decode":
-        cache = model.abstract_cache(batch, seq, jnp.float32)
-        token = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
-        return jax.make_jaxpr(model.decode)(params, cache, {"token": token})
-    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
-    return jax.make_jaxpr(
-        lambda p, t: decoder_forward(p, t, SMALL, mode=mode)[0])(params, tokens)
-
-
 @pytest.mark.parametrize("mode,batch,seq", [
     ("train", 1, 16), ("prefill", 1, 16), ("decode", 8, 32)])
 def test_stream_equals_the_traced_forward(mode, batch, seq):
-    stream = lower_network(SMALL, ShapeConfig(mode, seq, batch, mode))
     t = batch if mode == "decode" else seq
     assert (t * SMALL.top_k) % SMALL.n_experts == 0
-    traced = _traced_gemms(_trace(mode, batch, seq).jaxpr, GEMM_NAMES)
-
-    want = _by_shape((g.name, g.M, g.K, g.N, g.count) for g in stream.gemms)
-    got = _by_shape(traced)
-    assert got[0] == want[0]  # every non-expert (M, K, N) and its count
-    for shape, (name,) in want[1].items():
-        assert name in got[1][shape], (shape, name, got[1][shape])
-    assert got[2] == want[2]  # experts: (K, N) and MACs
-    assert sum(M * K * N * n for _, M, K, N, n in traced) == stream.total_macs
+    traced = assert_stream_equals_the_traced_forward(SMALL, mode, batch, seq, GEMM_NAMES)
     # the path follows the mode: absorbed GEMMs in decode only
     names = {row[0] for row in traced}
     assert names == GEMM_NAMES - ({"attn.kv_b"} if mode == "decode"

@@ -14,11 +14,9 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 #: every example and its CI-fast invocation. Adding an example without
 #: registering it here fails test_all_examples_are_covered.
 EXAMPLES = {
-    "quickstart.py": ["--smoke"],
+    "quickstart.py": [],
     "dse_explore.py": ["--m", "64", "--k", "2048", "--n", "147", "--pareto"],
     "network_explore.py": ["--arch", "smollm-135m", "--shape", "decode_32k"],
-    "serve_decode.py": ["--arch", "smollm-135m", "--gen-tokens", "8"],
-    "train_lm.py": ["--steps", "3", "--smoke"],
 }
 
 

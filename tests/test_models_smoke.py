@@ -1,5 +1,5 @@
-"""Per-arch smoke tests: reduced config, forward + train step on CPU,
-output shapes + finiteness; decode-vs-prefill cache consistency."""
+"""Per-arch smoke tests: reduced config, forward + loss gradient on
+CPU, output shapes + finiteness; decode-vs-prefill cache consistency."""
 
 import jax
 import jax.numpy as jnp
@@ -8,9 +8,6 @@ import pytest
 
 from repro.configs import REGISTRY, reduced
 from repro.models import build
-from repro.optim import OptConfig
-from repro.launch.steps import make_train_step
-from repro.optim import init_opt_state
 
 ARCHS = sorted(REGISTRY)
 
@@ -29,20 +26,17 @@ def _batch(cfg, B=2, S=32, seed=0):
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_train_step(arch):
+    """The loss and its gradient are finite, and the gradient is not
+    zero: ``Model.loss`` is the reference a backward-pass lowering
+    would be held against."""
     cfg = reduced(REGISTRY[arch])
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    opt = init_opt_state(params)
-    step = make_train_step(model, OptConfig(lr=1e-3))
-    batch = _batch(cfg)
-    p2, o2, loss = jax.jit(step)(params, opt, batch)
+    loss, grads = jax.jit(jax.value_and_grad(model.loss))(params, _batch(cfg))
     assert jnp.isfinite(loss), arch
-    # params actually changed
-    delta = sum(
-        float(jnp.sum(jnp.abs(a - b)))
-        for a, b in zip(jax.tree.leaves(p2), jax.tree.leaves(params))
-    )
-    assert delta > 0
+    leaves = jax.tree.leaves(grads)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in leaves), arch
+    assert sum(float(jnp.sum(jnp.abs(g))) for g in leaves) > 0, arch
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -62,21 +56,6 @@ def test_decode_matches_prefill(arch):
     got = np.asarray(got_l[:, 0])
     err = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)
     assert err < 5e-3, (arch, err)
-
-
-@pytest.mark.parametrize("arch", ARCHS)
-def test_microbatched_step_matches(arch):
-    """Gradient accumulation = same loss value (mean over microbatches)."""
-    cfg = reduced(REGISTRY[arch])
-    model = build(cfg)
-    params = model.init(jax.random.PRNGKey(3))
-    batch = _batch(cfg, B=4, S=16, seed=4)
-    l1 = float(model.loss(params, batch))
-    step = make_train_step(model, OptConfig(lr=0.0, weight_decay=0.0), microbatches=2)
-    opt = init_opt_state(params)
-    _, _, loss = jax.jit(step)(params, opt, batch)
-    # mean of per-microbatch losses == full-batch loss for mean-xent
-    assert abs(float(loss) - l1) < 5e-3, (arch, float(loss), l1)
 
 
 def test_vocab_logit_shapes():
